@@ -80,7 +80,7 @@ def kernels():
         ("kernel_topk_block", lambda: ops.blockwise_topk(x, 5)),
     ]:
         us = _time(lambda _: fn(), None, iters=3)
-        csv_line(name, us, "interpret=True(CPU)")
+        csv_line(name, us, f"interpret={ops.interpret_mode()}")
 
 
 # --------------------------------------------------------------------------
@@ -306,11 +306,9 @@ def wire(out_path: str = None):
     x = jax.random.normal(KEY, (D,))
     c = make_compressor("qsgd", levels=16)
     width = c.entry_bits
-    enc_entry = {"interpret": ops._interpret()}
-    for label, fused, use_pallas in (("fused_pallas", True, True),
-                                     ("fused_jnp", True, False),
-                                     ("legacy", False, False)):
-        codec = wire_codec(c, use_pallas=use_pallas, fused=fused)
+    enc_entry = {"interpret": ops.interpret_mode()}
+    for label, fused in (("fused_pallas", True), ("legacy", False)):
+        codec = wire_codec(c, fused=fused)
         enc = jax.jit(lambda v, k: codec.encode_batch(v[None], k[None])[0])
         us = _time_median(enc, x, KEY, reps=3, warmup=1)
         enc_entry[label] = round(us, 1)
@@ -392,7 +390,7 @@ def kernels_bench(out_path: str = None):
                 lambda a, e: ops.sign_unpack_ef_units(a, e, d), w, e2d)),
     }
 
-    report = {"interpret": ops._interpret(),
+    report = {"interpret": ops.interpret_mode(),
               "bucket": {"n_units": n, "d": d}}
     for cname, spec in codecs.items():
         width = spec["width"]

@@ -50,7 +50,7 @@ def main():
             out.append(toks)
     gen = jnp.stack(out, axis=1)
     print("prompts:", prompts[:2])
-    print("generated continuations:", gen[:2])
+    print("generated continuations:", jax.device_get(gen)[:2])
     print(f"served {BATCH} sequences x {GEN} tokens on "
           f"{mesh.devices.size} devices (seq-sharded KV cache)")
 

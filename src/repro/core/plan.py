@@ -40,6 +40,7 @@ from __future__ import annotations
 import bisect
 import dataclasses
 import functools
+import math
 from typing import Callable, List, Sequence, Tuple
 
 import jax
@@ -227,18 +228,25 @@ class UnitPlan:
         return out_flat
 
     def _assemble(self, out_leaves, out_flat):
-        outs, off = [], 0
+        sizes = [math.prod(shape) for shape in self.leaf_shapes]
+        offsets = [sum(sizes[:i]) for i in range(len(sizes))]
+        if out_flat is not None:
+            # Leaf offsets enter as runtime values: with static slices XLA
+            # rewrites reshape(slice(flat)) into slice(reshape(flat)), and
+            # a leaf with a narrow trailing dim (the (L, 256, 4) conv
+            # weights) then reshapes the WHOLE flat buffer to (N/1024,
+            # 256, 4), which TPU tiling pads 32x (172 GB at mamba2-1.3b).
+            offsets = jax.lax.optimization_barrier(
+                jnp.asarray(offsets, jnp.int32))
+        outs = []
         for i, (shape, dtype) in enumerate(zip(self.leaf_shapes,
                                                self.leaf_dtypes)):
-            size = 1
-            for s in shape:
-                size *= s
             if out_leaves[i] is not None:
                 outs.append(out_leaves[i])
             else:
-                outs.append(out_flat[off:off + size].reshape(shape)
-                            .astype(dtype))
-            off += size
+                outs.append(jax.lax.dynamic_slice(
+                    out_flat, (offsets[i],), (sizes[i],)).reshape(shape)
+                    .astype(dtype))
         return jax.tree_util.tree_unflatten(self.treedef, outs)
 
     # ---- execution --------------------------------------------------------

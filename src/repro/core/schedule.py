@@ -59,29 +59,6 @@ Array = jax.Array
 #: fusion_bytes sentinel: never close a message — everything fuses into one.
 FUSE_ALL = math.inf
 
-def register_barrier_batching_rule() -> None:
-    """jax 0.4.x ships optimization_barrier with no batching rule;
-    register the obvious pass-through (operands map 1:1 to outputs) so
-    the barrier survives vmap — scheduled execution is vmapped by
-    aggregate_simulated_workers, and models.model vmaps barriers in the
-    simulated multi-worker grads. This is the ONE copy of the shim
-    (models.model calls it too); idempotent, no-op on newer jax where
-    the rule exists upstream."""
-    try:
-        from jax.interpreters import batching as _batching
-        from jax._src.lax import lax as _lax_internal
-        barrier_p = _lax_internal.optimization_barrier_p
-        if barrier_p not in _batching.primitive_batchers:
-            def _barrier_batch(args, dims, **params):
-                return barrier_p.bind(*args, **params), dims
-            _batching.primitive_batchers[barrier_p] = _barrier_batch
-    except (ImportError, AttributeError):
-        pass
-
-
-register_barrier_batching_rule()
-
-
 def _order_after(xs: List[Array], token: Optional[Array]) -> List[Array]:
     """Identity on `xs` that the compiler may not hoist above `token`
     (the previous message's output): one optimization_barrier tying them

@@ -17,8 +17,8 @@ the accounted bits can be differentially tested against measured bytes
 (tests/test_wire.py).
 
 Codec formats (all legs little-endian; bit i of a packed leg lands in
-uint32 word i//32 at position i%32 — kernels/pack.py is the hot path,
-`kernels/ref.pack_bits_ref` the oracle):
+uint32 word i//32 at position i%32 — the Pallas kernels of kernels/ are
+the hot path, `kernels/ref.pack_fields_bitexpand_ref` the oracle):
 
   dense      raw f32 bytes                                  32 bits/entry
   qsgd(s)    f32 norm + b-bit offset-binary levels,         b = ceil(
@@ -229,21 +229,19 @@ def _u8_rows_to_vals(b: Array, k: int, wire_dtype: str) -> Array:
         b[:, :2 * k].reshape(b.shape[0], k, 2), jnp.bfloat16))
 
 
-def _pack_fields(vals: Array, width: int, use_pallas: bool) -> Array:
+def _pack_fields(vals: Array, width: int) -> Array:
     """int32 field vector (k,) with values < 2**width -> packed uint8
     bytes (whole uint32 words; LSB-first within each field). Word-wise:
     32-field chunks become `width` uint32 words via compile-time shifts
     (kernels/ref.pack_fields_tile) — the legacy k*width {0,1} int32 bit
     tensor (a 32x memory inflation) never exists. Byte-identical to the
     bit-expansion path (ref.pack_fields_bitexpand_ref pins it)."""
-    return _u32_to_u8(ops.pack_fields(vals, width, use_pallas=use_pallas))
+    return _u32_to_u8(ops.pack_fields(vals, width))
 
 
-def _unpack_fields(payload: Array, k: int, width: int,
-                   use_pallas: bool) -> Array:
+def _unpack_fields(payload: Array, k: int, width: int) -> Array:
     """Inverse of _pack_fields -> int32 (k,), word-wise shifts."""
-    return ops.unpack_fields(_u8_to_u32(payload), k, width,
-                             use_pallas=use_pallas)
+    return ops.unpack_fields(_u8_to_u32(payload), k, width)
 
 
 # --------------------------------------------------------------------------
@@ -258,10 +256,9 @@ class WireCodec:
     valid static argument under jit and a safe lru_cache key (message
     layouts cache on (schedule, codec)).
 
-    `use_pallas=False` (default) packs with the pure-jnp oracle — safe
-    under the vmapped bucket dispatches wire execution runs through;
-    `use_pallas=True` routes the word-packing through kernels/pack.py
-    (exercised on the non-vmapped entire-model path and in bench-wire).
+    Every pack and unpack runs a Pallas kernel of kernels/ (compiled on
+    TPU, interpreted on CPU: kernels/ops.interpret_mode); the pure-jnp
+    twins in kernels/ops.py are references the tests compare against.
 
     `fused=True` (default) routes the BATCH entry points (encode_batch /
     decode_batch / decode_ef_batch — what wire execution dispatches per
@@ -293,7 +290,6 @@ class WireCodec:
     and the bf16 value-cast variants.
     """
     comp: Compressor = Identity()
-    use_pallas: bool = False
     fused: bool = True
     wire_dtype: str = "float32"
     integrity: bool = False
@@ -453,12 +449,11 @@ class QSGDCodec(WireCodec):
         codes = q.astype(jnp.int32) + self.comp.levels
         return jnp.concatenate([
             _f32_to_u8(nrm[None]),
-            _pack_fields(codes, self.entry_bits, self.use_pallas)])
+            _pack_fields(codes, self.entry_bits)])
 
     def decode(self, payload: Array, d: int) -> Array:
         nrm = _u8_to_f32(payload[:4])[0]
-        codes = _unpack_fields(payload[4:], d, self.entry_bits,
-                               self.use_pallas)
+        codes = _unpack_fields(payload[4:], d, self.entry_bits)
         q = codes - self.comp.levels
         return q.astype(jnp.float32) * (nrm / self.comp.levels)
 
@@ -471,8 +466,7 @@ class QSGDCodec(WireCodec):
         if not self.fused:
             return super().encode_batch(x2d, keys)
         w, nrm = ops.qsgd_pack_units(x2d, keys, self.comp.levels,
-                                     self.entry_bits,
-                                     use_pallas=self.use_pallas)
+                                     self.entry_bits)
         return jnp.concatenate(
             [_f32_rows_to_u8(nrm[:, None]), _u32_rows_to_u8(w)], axis=1)
 
@@ -481,16 +475,14 @@ class QSGDCodec(WireCodec):
             return super().decode_batch(payloads, d)
         nrm, w = self._split(payloads)
         return ops.qsgd_unpack_units(w, nrm, d, self.comp.levels,
-                                     self.entry_bits,
-                                     use_pallas=self.use_pallas)
+                                     self.entry_bits)
 
     def decode_ef_batch(self, payloads: Array, e2d: Array, d: int):
         if not self.fused:
             return super().decode_ef_batch(payloads, e2d, d)
         nrm, w = self._split(payloads)
         return ops.qsgd_unpack_ef_units(w, nrm, e2d, d, self.comp.levels,
-                                        self.entry_bits,
-                                        use_pallas=self.use_pallas)
+                                        self.entry_bits)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -505,11 +497,11 @@ class TernGradCodec(WireCodec):
         t, s = self.comp._quantize(x.reshape(-1).astype(jnp.float32), key)
         codes = t.astype(jnp.int32) + 1
         return jnp.concatenate([
-            _f32_to_u8(s[None]), _pack_fields(codes, 2, self.use_pallas)])
+            _f32_to_u8(s[None]), _pack_fields(codes, 2)])
 
     def decode(self, payload: Array, d: int) -> Array:
         s = _u8_to_f32(payload[:4])[0]
-        t = _unpack_fields(payload[4:], d, 2, self.use_pallas) - 1
+        t = _unpack_fields(payload[4:], d, 2) - 1
         return t.astype(jnp.float32) * s
 
     def _split(self, payloads: Array):
@@ -519,8 +511,7 @@ class TernGradCodec(WireCodec):
     def encode_batch(self, x2d: Array, keys: Array) -> Array:
         if not self.fused:
             return super().encode_batch(x2d, keys)
-        w, s = ops.terngrad_pack_units(x2d, keys,
-                                       use_pallas=self.use_pallas)
+        w, s = ops.terngrad_pack_units(x2d, keys)
         return jnp.concatenate(
             [_f32_rows_to_u8(s[:, None]), _u32_rows_to_u8(w)], axis=1)
 
@@ -528,15 +519,13 @@ class TernGradCodec(WireCodec):
         if not self.fused:
             return super().decode_batch(payloads, d)
         s, w = self._split(payloads)
-        return ops.terngrad_unpack_units(w, s, d,
-                                         use_pallas=self.use_pallas)
+        return ops.terngrad_unpack_units(w, s, d)
 
     def decode_ef_batch(self, payloads: Array, e2d: Array, d: int):
         if not self.fused:
             return super().decode_ef_batch(payloads, e2d, d)
         s, w = self._split(payloads)
-        return ops.terngrad_unpack_ef_units(w, s, e2d, d,
-                                            use_pallas=self.use_pallas)
+        return ops.terngrad_unpack_ef_units(w, s, e2d, d)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -550,31 +539,27 @@ class SignSGDCodec(WireCodec):
         return 4 * words_for(d)
 
     def encode(self, x: Array, key: Array) -> Array:
-        bits = (x.reshape(-1) >= 0).astype(jnp.int32)
-        return _u32_to_u8(ops.pack_words(bits, use_pallas=self.use_pallas))
+        return _pack_fields((x.reshape(-1) >= 0).astype(jnp.int32), 1)
 
     def decode(self, payload: Array, d: int) -> Array:
-        bits = ops.unpack_words(_u8_to_u32(payload), d,
-                                use_pallas=self.use_pallas)
+        bits = _unpack_fields(payload, d, 1)
         return (2 * bits - 1).astype(jnp.float32)
 
     def encode_batch(self, x2d: Array, keys: Array) -> Array:
         if not self.fused:
             return super().encode_batch(x2d, keys)
         return _u32_rows_to_u8(
-            ops.sign_pack_units(x2d, use_pallas=self.use_pallas))
+            ops.sign_pack_units(x2d))
 
     def decode_batch(self, payloads: Array, d: int) -> Array:
         if not self.fused:
             return super().decode_batch(payloads, d)
-        return ops.sign_unpack_units(_u8_rows_to_u32(payloads), d,
-                                     use_pallas=self.use_pallas)
+        return ops.sign_unpack_units(_u8_rows_to_u32(payloads), d)
 
     def decode_ef_batch(self, payloads: Array, e2d: Array, d: int):
         if not self.fused:
             return super().decode_ef_batch(payloads, e2d, d)
-        return ops.sign_unpack_ef_units(_u8_rows_to_u32(payloads), e2d, d,
-                                        use_pallas=self.use_pallas)
+        return ops.sign_unpack_ef_units(_u8_rows_to_u32(payloads), e2d, d)
 
     def majority_vote(self, payloads: Array, d: int) -> Array:
         """(n_workers, nbytes) packed payloads -> one packed payload whose
@@ -585,13 +570,11 @@ class SignSGDCodec(WireCodec):
         zero word-padding bits vote 0 on both paths."""
         n = payloads.shape[0]
         if self.fused:
-            maj = ops.majority_words(_u8_rows_to_u32(payloads),
-                                     use_pallas=self.use_pallas)
+            maj = ops.majority_words(_u8_rows_to_u32(payloads))
             return _u32_to_u8(maj)
-        bits = jax.vmap(lambda p: ops.unpack_words(
-            _u8_to_u32(p), d, use_pallas=False))(payloads)
+        bits = jax.vmap(lambda p: _unpack_fields(p, d, 1))(payloads)
         maj = (2 * bits.sum(axis=0) >= n).astype(jnp.int32)
-        return _u32_to_u8(ops.pack_words(maj, use_pallas=self.use_pallas))
+        return _pack_fields(maj, 1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -608,10 +591,10 @@ class NaturalCodec(WireCodec):
         e, sgn, zero = self.comp._exponents(xf, key)
         bias = self.comp._BIAS + 1  # the compressor's own code offset
         code = jnp.where(zero, 0, sgn.astype(jnp.int32) * (e + bias))
-        return _pack_fields(code + 255, 9, self.use_pallas)
+        return _pack_fields(code + 255, 9)
 
     def decode(self, payload: Array, d: int) -> Array:
-        code = _unpack_fields(payload, d, 9, self.use_pallas) - 255
+        code = _unpack_fields(payload, d, 9) - 255
         return self._dequant(code)
 
     def _dequant(self, code: Array) -> Array:
@@ -637,13 +620,12 @@ class NaturalCodec(WireCodec):
         else:
             codes = jax.vmap(codes_of)(x2d, keys)
         return _u32_rows_to_u8(
-            ops.fields_pack_units(codes, 9, use_pallas=self.use_pallas))
+            ops.fields_pack_units(codes, 9))
 
     def decode_batch(self, payloads: Array, d: int) -> Array:
         if not self.fused:
             return super().decode_batch(payloads, d)
-        codes = ops.fields_unpack_units(_u8_rows_to_u32(payloads), d, 9,
-                                        use_pallas=self.use_pallas)
+        codes = ops.fields_unpack_units(_u8_rows_to_u32(payloads), d, 9)
         return self._dequant(codes - 255)
 
 
@@ -689,14 +671,12 @@ class SparseCodec(WireCodec):
         payload = self._c(d).encode(x, key)
         return jnp.concatenate([
             _vals_to_u8(payload["val"], self.wire_dtype),
-            _pack_fields(payload["idx"].astype(jnp.int32), index_bits(d),
-                         self.use_pallas)])
+            _pack_fields(payload["idx"].astype(jnp.int32), index_bits(d))])
 
     def decode(self, payload: Array, d: int) -> Array:
         k = self._k(d)
         val = _u8_to_vals(payload[:self._vb(d)], k, self.wire_dtype)
-        idx = _unpack_fields(payload[self._vb(d):], k, index_bits(d),
-                             self.use_pallas)
+        idx = _unpack_fields(payload[self._vb(d):], k, index_bits(d))
         return jnp.zeros((d,), jnp.float32).at[idx].set(val)
 
     def encode_batch(self, x2d: Array, keys: Array) -> Array:
@@ -714,8 +694,7 @@ class SparseCodec(WireCodec):
             val, idx = val[None], idx[None]
         else:
             val, idx = jax.vmap(records_of)(x2d, keys)
-        words = ops.fields_pack_units(idx, index_bits(d),
-                                      use_pallas=self.use_pallas)
+        words = ops.fields_pack_units(idx, index_bits(d))
         return jnp.concatenate(
             [_val_rows_to_u8(val, self.wire_dtype),
              _u32_rows_to_u8(words)], axis=1)
@@ -727,8 +706,7 @@ class SparseCodec(WireCodec):
         vb = self._vb(d)
         val = _u8_rows_to_vals(payloads[:, :vb], k, self.wire_dtype)
         idx = ops.fields_unpack_units(_u8_rows_to_u32(payloads[:, vb:]),
-                                      k, index_bits(d),
-                                      use_pallas=self.use_pallas)
+                                      k, index_bits(d))
         scatter = lambda v, i: jnp.zeros((d,), jnp.float32).at[i].set(v)
         if payloads.shape[0] == 1:
             return scatter(val[0], idx[0])[None]
@@ -739,8 +717,7 @@ class SparseCodec(WireCodec):
 # registry
 # --------------------------------------------------------------------------
 
-def wire_codec(comp: Compressor, use_pallas: bool = False,
-               fused: bool = True,
+def wire_codec(comp: Compressor, fused: bool = True,
                wire_dtype: str = "float32",
                integrity: bool = False) -> WireCodec:
     """The WireCodec materializing `comp`'s payloads. Raises ValueError
@@ -751,8 +728,7 @@ def wire_codec(comp: Compressor, use_pallas: bool = False,
     (dense/sparse codecs only — the quantized codecs raise).
     `integrity=True` adds the Fletcher-32 header word per fused message
     (4 bytes/message; payloads and numerics unchanged)."""
-    kw = dict(use_pallas=use_pallas, fused=fused, wire_dtype=wire_dtype,
-              integrity=integrity)
+    kw = dict(fused=fused, wire_dtype=wire_dtype, integrity=integrity)
     base = comp.base if hasattr(comp, "base") else comp  # PerDimRatio
     if isinstance(base, (TopK, RandomK)):
         return SparseCodec(comp=comp, **kw)

@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the compression hot path (+ fused RMSNorm).
 
-Kernels run with interpret=True on this CPU container (validation); on a
-real TPU set REPRO_PALLAS_INTERPRET=0.
+How a kernel runs follows the platform (`ops.interpret_mode`): Mosaic
+compiles it on TPU; on CPU its body runs as ordinary jax ops.
 
 The wire hot path is the fused single-launch compress+pack family in
 `ops` — one kernel launch per UnitPlan bucket, payloads byte-identical
@@ -24,9 +24,9 @@ to the legacy three-pass (quantize -> bit-expand -> word-pack) pipeline:
   `ops.count_pallas_calls` — the deterministic traffic + dispatch
   accounting BENCH_kernels.json gates on.
 
-Every fused op has a pure-jnp fallback running the identical tile
-arithmetic (`kernels/ref.py`), so payloads match bit-for-bit with
-pallas on or off; in-kernel stochastic rounding draws come from
+Every fused op has a pure-jnp twin running the identical tile
+arithmetic (`kernels/ref.py`), the reference the tests compare the
+kernels against bit for bit; in-kernel stochastic rounding draws come from
 `kernels/prng.py` (bit-exact threefry reimplementation of the
 `jax.random.uniform` draw the simulated compressors make).
 """

@@ -2,20 +2,21 @@
 
 Handle padding/reshaping from arbitrary flat gradients to the kernels'
 (rows, 512) tiled layout, generate the stochastic-rounding uniforms, and
-fall back to the pure-jnp reference when pallas is disabled. On this CPU
-container the kernels run with interpret=True (body executed in Python —
-correctness only); on TPU set REPRO_PALLAS_INTERPRET=0.
+run the pure-jnp reference instead when a caller passes use_pallas=False
+(the tests compare the two). How a kernel runs follows the platform the
+computation is traced for (`interpret_mode`): compiled by Mosaic on TPU,
+interpreted on CPU, refused anywhere else.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import prng, ref
-from repro.kernels.pack import PACK_R, fields_pack_pallas, fields_unpack_pallas
+from repro.kernels.pack import (fields_pack_pallas, fields_unpack_pallas,
+                                pack_tile_rows)
 from repro.kernels.qsgd import (BLOCK_C, BLOCK_R, qsgd_pack_pallas_rows,
                                 qsgd_pallas, qsgd_pallas_rows,
                                 qsgd_unpack_pallas_rows)
@@ -30,8 +31,18 @@ from repro.kernels.topk_mask import topk_mask_pallas
 Array = jax.Array
 
 
-def _interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+def interpret_mode() -> bool:
+    """The Pallas `interpret` flag for the default backend: False (Mosaic
+    compiles the kernel) on TPU, True (the body runs as jax ops) on CPU.
+    Any other platform has no Pallas path here, so it is an error rather
+    than a silent fallback."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(f"no Pallas mode for platform {platform!r}: the "
+                       f"kernels compile for tpu and are interpreted on cpu")
 
 
 def _tile(x: Array):
@@ -59,7 +70,7 @@ def qsgd_compress(x: Array, key: Array, levels: int = 16,
     xt, d = _tile(xf)
     noise = jax.random.uniform(key, xt.shape)
     if use_pallas:
-        out = qsgd_pallas(xt, noise, norm, levels, interpret=_interpret())
+        out = qsgd_pallas(xt, noise, norm, levels, interpret=interpret_mode())
     else:
         out = ref.qsgd_ref(xt, noise, norm, levels)
     return _untile(out, d, x.shape).astype(x.dtype)
@@ -72,7 +83,7 @@ def terngrad_compress(x: Array, key: Array, use_pallas: bool = True) -> Array:
     xt, d = _tile(xf)
     noise = jax.random.uniform(key, xt.shape)
     if use_pallas:
-        out = terngrad_pallas(xt, noise, scale, interpret=_interpret())
+        out = terngrad_pallas(xt, noise, scale, interpret=interpret_mode())
     else:
         out = ref.terngrad_ref(xt, noise, scale)
     return _untile(out, d, x.shape).astype(x.dtype)
@@ -87,7 +98,7 @@ def blockwise_topk(x: Array, k_per_block: int,
     xf = x.astype(jnp.float32)
     xt, d = _tile(xf)
     if use_pallas:
-        out = topk_mask_pallas(xt, k_per_block, interpret=_interpret())
+        out = topk_mask_pallas(xt, k_per_block, interpret=interpret_mode())
     else:
         out = ref.topk_mask_ref(xt, k_per_block)
     return _untile(out, d, x.shape).astype(x.dtype)
@@ -142,7 +153,7 @@ def qsgd_compress_units(x2d: Array, keys: Array, levels: int = 16,
     scales = _row_scales(norms, rpu, R)
     if use_pallas:
         out = qsgd_pallas_rows(xt, noise, scales, levels,
-                               interpret=_interpret())
+                               interpret=interpret_mode())
     else:
         out = ref.qsgd_ref(xt, noise, scales, levels)  # (R,1) broadcasts
     return out[:rows].reshape(n, rpu * BLOCK_C)[:, :d].astype(x2d.dtype)
@@ -161,7 +172,7 @@ def terngrad_compress_units(x2d: Array, keys: Array,
     scales = _row_scales(scales_u, rpu, R)
     if use_pallas:
         out = terngrad_pallas_rows(xt, noise, scales,
-                                   interpret=_interpret())
+                                   interpret=interpret_mode())
     else:
         out = ref.terngrad_ref(xt, noise, scales)
     return out[:rows].reshape(n, rpu * BLOCK_C)[:, :d].astype(x2d.dtype)
@@ -201,60 +212,6 @@ def plan_compress(plan, grads, key: Array, kind: str = "qsgd", **kw):
 
 
 # --------------------------------------------------------------------------
-# bit-vector <-> uint32-word packing (the wire codecs' hot inner loop)
-# --------------------------------------------------------------------------
-
-def _cdiv(a: int, b: int) -> int:
-    return -(-a // b)
-
-
-@partial(jax.jit, static_argnames=("use_pallas",))
-def pack_words(bits: Array, use_pallas: bool = False) -> Array:
-    """{0,1} int32 flat bit vector (n,) -> uint32 words (ceil(n/32),).
-
-    Bit i lands in word i//32 at position i%32 (little-endian bit order).
-    `use_pallas=False` (the default — safe under vmap, which is how wire
-    codecs run inside bucket dispatches) packs with the pure-jnp oracle;
-    `use_pallas=True` tiles to (rows, 512) and runs the kernels/pack.py
-    word-packing kernel.
-    """
-    n = bits.shape[0]
-    if n == 0:
-        return jnp.zeros((0,), jnp.uint32)
-    W = _cdiv(n, 32)
-    if use_pallas:
-        from repro.kernels.pack import PACK_C, PACK_R, pack_bits_pallas
-        rows = _cdiv(_cdiv(n, PACK_C), PACK_R) * PACK_R
-        bt = jnp.pad(bits.astype(jnp.int32),
-                     (0, rows * PACK_C - n)).reshape(rows, PACK_C)
-        words = pack_bits_pallas(bt, interpret=_interpret()).reshape(-1)
-    else:
-        pad = (-n) % 32
-        bt = jnp.pad(bits.astype(jnp.int32), (0, pad)).reshape(-1, 32)
-        words = ref.pack_bits_ref(bt).reshape(-1)
-    return words[:W]
-
-
-@partial(jax.jit, static_argnames=("n", "use_pallas"))
-def unpack_words(words: Array, n: int, use_pallas: bool = False) -> Array:
-    """uint32 words -> the first `n` bits as a {0,1} int32 vector.
-    Inverse of pack_words (same bit order, same pallas/jnp switch)."""
-    if n == 0:
-        return jnp.zeros((0,), jnp.int32)
-    if use_pallas:
-        from repro.kernels.pack import (PACK_R, WORDS_PER_ROW,
-                                        unpack_bits_pallas)
-        W = words.shape[0]
-        rows = _cdiv(_cdiv(W, WORDS_PER_ROW), PACK_R) * PACK_R
-        wt = jnp.pad(words, (0, rows * WORDS_PER_ROW - W)).reshape(
-            rows, WORDS_PER_ROW)
-        bits = unpack_bits_pallas(wt, interpret=_interpret()).reshape(-1)
-    else:
-        bits = ref.unpack_bits_ref(words.reshape(-1, 1)).reshape(-1)
-    return bits[:n]
-
-
-# --------------------------------------------------------------------------
 # fused single-launch compress+pack ops (the wire hot path).
 #
 # A bucket matrix (n, d) becomes packed uint32 payload words in ONE kernel
@@ -262,47 +219,55 @@ def unpack_words(words: Array, n: int, use_pallas: bool = False) -> Array:
 # uniforms generated in-kernel from per-row threefry key columns
 # (kernels/prng.py, bit-exact to jax.random), the {0,1} bit tensor of the
 # legacy quantize -> bit-expand -> word-pack pipeline never materialized.
-# The jnp fallbacks run the IDENTICAL arithmetic (same tile helpers from
-# kernels/ref.py) outside pallas_call, so payloads are byte-identical on
-# both paths. Decode mirrors encode; the *_unpack_ef variants chain the
+# The use_pallas=False twins run the IDENTICAL arithmetic (same tile
+# helpers from kernels/ref.py) outside pallas_call — the references the
+# tests hold the kernels to byte for byte; the codecs always run the
+# kernels. Decode mirrors encode; the *_unpack_ef variants chain the
 # single decode launch with the error-feedback residual m = e - xhat
-# formed in the CALLER's regime — in-kernel the CPU backend's LLVM
-# fp-contraction turns the mul+sub into an FMA through every JAX-level
-# barrier, silently changing the low bits the EF discipline is pinned to.
+# formed in the CALLER's regime (they are deliberately not jitted) —
+# compiled together, the CPU backend's LLVM fp-contraction turns the
+# mul+sub into an FMA through every JAX-level barrier, silently changing
+# the low bits the EF discipline is pinned to.
 # --------------------------------------------------------------------------
+
+def _cdiv(a: int, b: int) -> int:
+    return -(-a // b)
+
 
 def words_per_unit(d: int, width: int) -> int:
     """uint32 payload words of one unit's packed field leg."""
     return _cdiv(d * width, 32)
 
 
-def _tile_rows(x2d: Array, row_mult: int):
+def _tile_rows(x2d: Array):
     """(n, d) bucket -> ((R, 512) tiles, live_rows, rows_per_unit), with
-    R % row_mult == 0 (the pack kernels use row_mult=PACK_R=8, far tighter
-    than the legacy BLOCK_R=256 padding)."""
+    R = pack_tile_rows(live_rows)."""
     n, d = x2d.shape
     rpu = _cdiv(d, BLOCK_C)
     xp = jnp.pad(x2d, ((0, 0), (0, rpu * BLOCK_C - d)))
     rows = n * rpu
-    R = _cdiv(rows, row_mult) * row_mult
+    R = pack_tile_rows(rows)
     xt = jnp.pad(xp.reshape(rows, BLOCK_C), ((0, R - rows), (0, 0)))
     return xt, rows, rpu
 
 
-def _tile_word_rows(w2d: Array, width: int, rpu: int, row_mult: int):
-    """(n, words_per_unit) payload words -> (R, 16*width) word tiles."""
+def _tile_word_rows(w2d: Array, width: int, rpu: int):
+    """(n, words_per_unit) payload words -> ((R//8, 128*width) word tiles,
+    live_rows, R): each unit's words padded to its rpu tile rows."""
     n, wpu = w2d.shape
-    wpr = (BLOCK_C // 32) * width
-    wp = jnp.pad(w2d, ((0, 0), (0, rpu * wpr - wpu)))
+    wpr = (BLOCK_C // 32) * width                    # words per tile row
     rows = n * rpu
-    R = _cdiv(rows, row_mult) * row_mult
-    return jnp.pad(wp.reshape(rows, wpr), ((0, R - rows), (0, 0))), rows
+    R = pack_tile_rows(rows)
+    wp = jnp.pad(w2d, ((0, 0), (0, rpu * wpr - wpu))).reshape(-1)
+    wt = jnp.pad(wp, (0, (R - rows) * wpr)).reshape(R // 8, 8 * wpr)
+    return wt, rows, R
 
 
 def _untile_words(wt: Array, n: int, rows: int, wpu: int) -> Array:
-    """(R, 16*width) word tiles -> (n, words_per_unit), dropping the
-    in-unit word padding and the row padding."""
-    return wt[:rows].reshape(n, -1)[:, :wpu]
+    """Word tiles -> (n, words_per_unit), dropping the in-unit word
+    padding and the row padding."""
+    wpr = wt.shape[1] // 8
+    return wt.reshape(-1)[:rows * wpr].reshape(n, -1)[:, :wpu]
 
 
 def _untile_rows(xt: Array, n: int, rows: int, d: int) -> Array:
@@ -329,12 +294,13 @@ def _key_cols(keys: Array, rpu: int, R: int):
 
 def _tile_positions(R: int, rpu: int) -> Array:
     """In-unit flat position of every (row, lane) of a full tile — the
-    jnp-fallback twin of the kernels' _row_positions."""
+    jnp twin of the kernels' _row_positions."""
     row = jnp.arange(R, dtype=jnp.int32)[:, None]
     col = jnp.arange(BLOCK_C, dtype=jnp.int32)[None, :]
     return (row % rpu) * BLOCK_C + col
 
 
+@partial(jax.jit, static_argnames=("levels", "width", "use_pallas"))
 def qsgd_pack_units(x2d: Array, keys: Array, levels: int, width: int,
                     use_pallas: bool = True):
     """Fused QSGD encode of a whole bucket: (n, d) f32 + per-unit keys ->
@@ -346,34 +312,35 @@ def qsgd_pack_units(x2d: Array, keys: Array, levels: int, width: int,
     xf = x2d.astype(jnp.float32)
     n, d = xf.shape
     nrms = jnp.linalg.norm(xf, axis=1) + 1e-12
-    xt, rows, rpu = _tile_rows(xf, PACK_R)
+    xt, rows, rpu = _tile_rows(xf)
     R = xt.shape[0]
     k0, k1 = _key_cols(keys, rpu, R)
     nc = _unit_col(nrms, rpu, R)
     if use_pallas:
         wt = qsgd_pack_pallas_rows(xt, k0, k1, nc, levels, width,
-                                   d=d, rpu=rpu, interpret=_interpret())
+                                   d=d, rpu=rpu, interpret=interpret_mode())
     else:
         pos = _tile_positions(R, rpu)
-        u = prng.uniform_at(k0, k1, pos, d)
+        u = prng.uniform_at(k0, k1, pos)
         codes = jnp.where(pos < d,
                           ref.qsgd_codes_ref(xt, u, nc, levels), 0)
         wt = ref.pack_fields_tile(codes, width)
     return _untile_words(wt, n, rows, words_per_unit(d, width)), nrms
 
 
+@partial(jax.jit, static_argnames=("d", "levels", "width", "use_pallas"))
 def qsgd_unpack_units(words: Array, nrms: Array, d: int, levels: int,
                       width: int, use_pallas: bool = True) -> Array:
     """Fused QSGD decode: (n, words_per_unit) uint32 + payload norms ->
     (n, d) f32 — unpack + dequantize in one launch."""
     n = words.shape[0]
     rpu = _cdiv(d, BLOCK_C)
-    wt, rows = _tile_word_rows(words, width, rpu, PACK_R)
+    wt, rows, R = _tile_word_rows(words, width, rpu)
     # divide in the CALLER's regime (ref.qsgd_decode_ref explains why)
-    fc = _unit_col(nrms.astype(jnp.float32) / levels, rpu, wt.shape[0])
+    fc = _unit_col(nrms.astype(jnp.float32) / levels, rpu, R)
     if use_pallas:
         xt = qsgd_unpack_pallas_rows(wt, fc, levels, width,
-                                     interpret=_interpret())
+                                     interpret=interpret_mode())
     else:
         codes = ref.unpack_fields_tile(wt, width)
         xt = ref.qsgd_decode_ref(codes, fc, levels)
@@ -391,40 +358,43 @@ def qsgd_unpack_ef_units(words: Array, nrms: Array, e2d: Array, d: int,
     laundering, fast-math flags — all verified ineffective), which flips
     the residual's low bits versus the two-step rounding the wire EF
     discipline (core/wire.py with-state path) is bitwise-pinned to."""
-    xhat = qsgd_unpack_units(words, nrms, d, levels, width, use_pallas)
+    xhat = qsgd_unpack_units(words, nrms, d, levels, width,
+                             use_pallas=use_pallas)
     return xhat, e2d.astype(jnp.float32) - xhat
 
 
+@partial(jax.jit, static_argnames=("use_pallas",))
 def terngrad_pack_units(x2d: Array, keys: Array, use_pallas: bool = True):
     """Fused TernGrad encode: (n, d) f32 + per-unit keys -> ((n,
     words_per_unit(d, 2)) uint32 words, (n,) f32 scales incl. +1e-12)."""
     xf = x2d.astype(jnp.float32)
     n, d = xf.shape
     scales = jnp.max(jnp.abs(xf), axis=1) + 1e-12
-    xt, rows, rpu = _tile_rows(xf, PACK_R)
+    xt, rows, rpu = _tile_rows(xf)
     R = xt.shape[0]
     k0, k1 = _key_cols(keys, rpu, R)
     sc = _unit_col(scales, rpu, R)
     if use_pallas:
         wt = terngrad_pack_pallas_rows(xt, k0, k1, sc, d=d, rpu=rpu,
-                                       interpret=_interpret())
+                                       interpret=interpret_mode())
     else:
         pos = _tile_positions(R, rpu)
-        u = prng.uniform_at(k0, k1, pos, d)
+        u = prng.uniform_at(k0, k1, pos)
         codes = jnp.where(pos < d, ref.terngrad_codes_ref(xt, u, sc), 0)
         wt = ref.pack_fields_tile(codes, 2)
     return _untile_words(wt, n, rows, words_per_unit(d, 2)), scales
 
 
+@partial(jax.jit, static_argnames=("d", "use_pallas"))
 def terngrad_unpack_units(words: Array, scales: Array, d: int,
                           use_pallas: bool = True) -> Array:
     """Fused TernGrad decode: words + payload scales -> (n, d) f32."""
     n = words.shape[0]
     rpu = _cdiv(d, BLOCK_C)
-    wt, rows = _tile_word_rows(words, 2, rpu, PACK_R)
-    sc = _unit_col(scales.astype(jnp.float32), rpu, wt.shape[0])
+    wt, rows, R = _tile_word_rows(words, 2, rpu)
+    sc = _unit_col(scales.astype(jnp.float32), rpu, R)
     if use_pallas:
-        xt = terngrad_unpack_pallas_rows(wt, sc, interpret=_interpret())
+        xt = terngrad_unpack_pallas_rows(wt, sc, interpret=interpret_mode())
     else:
         xt = ref.terngrad_decode_ref(ref.unpack_fields_tile(wt, 2), sc)
     return _untile_rows(xt, n, rows, d)
@@ -434,19 +404,20 @@ def terngrad_unpack_ef_units(words: Array, scales: Array, e2d: Array,
                              d: int, use_pallas: bool = True):
     """TernGrad decode + EF residual (caller-regime subtract, see
     qsgd_unpack_ef_units for the fp-contraction rationale) -> (xhat, m)."""
-    xhat = terngrad_unpack_units(words, scales, d, use_pallas)
+    xhat = terngrad_unpack_units(words, scales, d, use_pallas=use_pallas)
     return xhat, e2d.astype(jnp.float32) - xhat
 
 
+@partial(jax.jit, static_argnames=("use_pallas",))
 def sign_pack_units(x2d: Array, use_pallas: bool = True) -> Array:
     """Fused signSGD encode: (n, d) f32 -> (n, words_per_unit(d, 1))
     uint32 sign words (bit = x >= 0). No statistic, no randomness."""
     xf = x2d.astype(jnp.float32)
     n, d = xf.shape
-    xt, rows, rpu = _tile_rows(xf, PACK_R)
+    xt, rows, rpu = _tile_rows(xf)
     if use_pallas:
         wt = sign_pack_pallas_rows(xt, d=d, rpu=rpu,
-                                   interpret=_interpret())
+                                   interpret=interpret_mode())
     else:
         pos = _tile_positions(xt.shape[0], rpu)
         codes = jnp.where(pos < d, ref.sign_codes_ref(xt), 0)
@@ -454,14 +425,15 @@ def sign_pack_units(x2d: Array, use_pallas: bool = True) -> Array:
     return _untile_words(wt, n, rows, words_per_unit(d, 1))
 
 
+@partial(jax.jit, static_argnames=("d", "use_pallas"))
 def sign_unpack_units(words: Array, d: int,
                       use_pallas: bool = True) -> Array:
     """Fused signSGD decode: sign words -> (n, d) f32 in {-1, +1}."""
     n = words.shape[0]
     rpu = _cdiv(d, BLOCK_C)
-    wt, rows = _tile_word_rows(words, 1, rpu, PACK_R)
+    wt, rows, _ = _tile_word_rows(words, 1, rpu)
     if use_pallas:
-        xt = sign_unpack_pallas_rows(wt, interpret=_interpret())
+        xt = sign_unpack_pallas_rows(wt, interpret=interpret_mode())
     else:
         xt = ref.sign_decode_ref(ref.unpack_fields_tile(wt, 1))
     return _untile_rows(xt, n, rows, d)
@@ -471,10 +443,11 @@ def sign_unpack_ef_units(words: Array, e2d: Array, d: int,
                          use_pallas: bool = True):
     """signSGD decode + EF residual (caller-regime subtract, see
     qsgd_unpack_ef_units for the fp-contraction rationale) -> (xhat, m)."""
-    xhat = sign_unpack_units(words, d, use_pallas)
+    xhat = sign_unpack_units(words, d, use_pallas=use_pallas)
     return xhat, e2d.astype(jnp.float32) - xhat
 
 
+@partial(jax.jit, static_argnames=("width", "use_pallas"))
 def fields_pack_units(f2d: Array, width: int,
                       use_pallas: bool = True) -> Array:
     """Generic word-wise field packing of a bucket: (n, k) int32 fields
@@ -482,47 +455,50 @@ def fields_pack_units(f2d: Array, width: int,
     each unit's leg separately word-padded (the wire padding rule). The
     single-launch pack leg of the natural and sparse-index codecs."""
     n, k = f2d.shape
-    ft, rows, rpu = _tile_rows(f2d.astype(jnp.int32), PACK_R)
+    ft, rows, rpu = _tile_rows(f2d.astype(jnp.int32))
     pos = _tile_positions(ft.shape[0], rpu)
     ft = jnp.where(pos < k, ft, 0)                   # zero word padding
     if use_pallas:
-        wt = fields_pack_pallas(ft, width, interpret=_interpret())
+        wt = fields_pack_pallas(ft, width, interpret=interpret_mode())
     else:
         wt = ref.pack_fields_tile(ft, width)
     return _untile_words(wt, n, rows, words_per_unit(k, width))
 
 
+@partial(jax.jit, static_argnames=("k", "width", "use_pallas"))
 def fields_unpack_units(words: Array, k: int, width: int,
                         use_pallas: bool = True) -> Array:
     """Inverse of fields_pack_units -> (n, k) int32."""
     n = words.shape[0]
     rpu = _cdiv(k, BLOCK_C)
-    wt, rows = _tile_word_rows(words, width, rpu, PACK_R)
+    wt, rows, _ = _tile_word_rows(words, width, rpu)
     if use_pallas:
-        ft = fields_unpack_pallas(wt, width, interpret=_interpret())
+        ft = fields_unpack_pallas(wt, width, interpret=interpret_mode())
     else:
         ft = ref.unpack_fields_tile(wt, width)
     return _untile_rows(ft, n, rows, k)
 
 
 def pack_fields(vals: Array, width: int,
-                use_pallas: bool = False) -> Array:
+                use_pallas: bool = True) -> Array:
     """(k,) int32 fields -> (ceil(k*width/32),) uint32 words via WORD-WISE
-    shifts — the jnp fallback of the wire codecs' field legs. Replaces the
+    shifts — the per-unit field legs of the wire codecs (width 1 packs a
+    {0,1} bit vector: bit i in word i//32 at position i%32). Replaces the
     legacy bit-expansion path (ref.pack_fields_bitexpand_ref, kept as the
     byte-identity oracle), whose k*width int32 bit tensor was a 32x
-    memory inflation even with Pallas off."""
+    memory inflation."""
     return fields_pack_units(vals[None], width, use_pallas=use_pallas)[0]
 
 
 def unpack_fields(words: Array, k: int, width: int,
-                  use_pallas: bool = False) -> Array:
+                  use_pallas: bool = True) -> Array:
     """Inverse of pack_fields -> int32 (k,)."""
     return fields_unpack_units(words[None], k, width,
                                use_pallas=use_pallas)[0]
 
 
-def majority_words(words2d: Array, use_pallas: bool = False) -> Array:
+@partial(jax.jit, static_argnames=("use_pallas",))
+def majority_words(words2d: Array, use_pallas: bool = True) -> Array:
     """(n_workers, W) uint32 packed sign words -> (W,) majority-vote
     words (ties -> +1), computed DIRECTLY on the packed words via
     bit-sliced ripple-carry counting — the {0,1} bit tensor never exists
@@ -531,7 +507,7 @@ def majority_words(words2d: Array, use_pallas: bool = False) -> Array:
     if use_pallas:
         Wp = _cdiv(W, MAJ_C) * MAJ_C
         wp = jnp.pad(words2d, ((0, 0), (0, Wp - W)))
-        return majority_pallas(wp, interpret=_interpret())[:W]
+        return majority_pallas(wp, interpret=interpret_mode())[:W]
     return ref.majority_words_ref(words2d)
 
 
@@ -673,7 +649,7 @@ def rmsnorm(x: Array, gamma: Array, eps: float = 1e-5,
     pad = (-R) % NR
     xp = jnp.pad(xr, ((0, pad), (0, 0)))
     if use_pallas:
-        out = rmsnorm_pallas(xp, gamma, eps, interpret=_interpret())
+        out = rmsnorm_pallas(xp, gamma, eps, interpret=interpret_mode())
     else:
         out = ref.rmsnorm_ref(xp, gamma, eps)
     return out[:R].reshape(shape)

@@ -1,18 +1,18 @@
-"""Pallas TPU kernels: bit-vector <-> uint32-word packing.
+"""Pallas TPU kernels: width-bit field packing, and the tile geometry every
+pack/unpack kernel shares.
 
-The hot inner loop of every wire codec (core/wire.py) is turning a {0,1}
-bit stream into dense uint32 words and back — b-bit quantization levels,
-1-bit signs, index records all reduce to it. Pure VPU work: a (rows, 512)
-bit tile packs into (rows, 16) words per grid step via one weighted-sum
-reduction (bit i of a row lands in word i//32 at position i%32 —
-little-endian bit order, the layout `kernels/ref.pack_bits_ref` oracles
-and the jnp fallback reproduce bit for bit).
+The hot inner loop of every wire codec (core/wire.py) is turning integer
+fields (b-bit quantization levels, 1-bit signs, index records) into dense
+uint32 words and back. A tile row holds PACK_C = 512 fields of one
+compression unit; eight tile rows pack into one row of 128*width words
+(kernels/ref.pack_fields_tile), so the words read row-major are the
+little-endian bit stream of the fields and both sides of the kernel keep
+a lane-dense minor dimension.
 
-Tiling: 512 bit columns (4 lane groups of 128) so the input side is
-lane-aligned; PACK_R rows per grid step. The 16-word output tile is
-narrower than one lane group — fine under interpret mode (this CPU
-container) and acceptable on TPU since the output is 32x smaller than the
-input stream it compresses.
+Grid: a tile of R rows (R a multiple of PACK_MIN_R) is walked in blocks
+of min(R, PACK_BLOCK_R) rows; `pack_tile_rows` rounds a live row count
+up to such an R. qsgd/terngrad/sign fuse their quantizers in front of the
+same tile packer (kernels/{qsgd,terngrad,sign}.py) with the same specs.
 """
 from __future__ import annotations
 
@@ -24,102 +24,81 @@ from jax.experimental import pallas as pl
 
 from repro.kernels import ref
 
-PACK_R = 8             # rows per grid step (sublane multiple)
-PACK_C = 512           # bit columns per row (lane multiple)
-WORDS_PER_ROW = PACK_C // 32
+PACK_C = 512           # fields per tile row (four 128-lane groups)
+PACK_MIN_R = 64        # smallest tile: 8 rows of packed words
+PACK_BLOCK_R = 1024    # tile rows per grid step once a tile outgrows one
+_WR = ref.ROWS_PER_WORD_ROW
 
 
-def _pack_kernel(b_ref, o_ref):
-    b = b_ref[...]                                   # (R, 512) int32 {0,1}
-    rows = b.shape[0]
-    w = b.reshape(rows, WORDS_PER_ROW, 32).astype(jnp.uint32)
-    weights = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
-    o_ref[...] = (w * weights).sum(axis=-1).astype(jnp.uint32)
+def pack_tile_rows(rows: int) -> int:
+    """Tile rows holding `rows` live rows: a multiple of PACK_MIN_R up to
+    one block, a multiple of PACK_BLOCK_R beyond it."""
+    mult = PACK_MIN_R if rows <= PACK_BLOCK_R else PACK_BLOCK_R
+    return -(-max(rows, 1) // mult) * mult
 
 
-def _unpack_kernel(w_ref, o_ref):
-    w = w_ref[...]                                   # (R, 16) uint32
-    rows = w.shape[0]
-    bits = (w[..., None] >> jnp.arange(32, dtype=jnp.uint32)) & jnp.uint32(1)
-    o_ref[...] = bits.reshape(rows, PACK_C).astype(jnp.int32)
+def _block_rows(R: int) -> int:
+    assert R == pack_tile_rows(R), R
+    return min(R, PACK_BLOCK_R)
 
 
-def pack_bits_pallas(bits: jax.Array, *, interpret: bool = True) -> jax.Array:
-    """(R, 512) {0,1} int32 with R % PACK_R == 0 -> (R, 16) uint32 words."""
-    R, C = bits.shape
-    assert R % PACK_R == 0 and C == PACK_C, (R, C)
-    return pl.pallas_call(
-        _pack_kernel,
-        grid=(R // PACK_R,),
-        in_specs=[pl.BlockSpec((PACK_R, PACK_C), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((PACK_R, WORDS_PER_ROW), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, WORDS_PER_ROW), jnp.uint32),
-        interpret=interpret,
-    )(bits)
+def pack_grid(R: int):
+    return (R // _block_rows(R),)
 
 
-def unpack_bits_pallas(words: jax.Array, *,
-                       interpret: bool = True) -> jax.Array:
-    """(R, 16) uint32 with R % PACK_R == 0 -> (R, 512) {0,1} int32."""
-    R, W = words.shape
-    assert R % PACK_R == 0 and W == WORDS_PER_ROW, (R, W)
-    return pl.pallas_call(
-        _unpack_kernel,
-        grid=(R // PACK_R,),
-        in_specs=[pl.BlockSpec((PACK_R, WORDS_PER_ROW), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((PACK_R, PACK_C), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, PACK_C), jnp.int32),
-        interpret=interpret,
-    )(words)
+def tile_spec(R: int, cols: int = PACK_C) -> pl.BlockSpec:
+    """Block of tile rows: (block, 512) fields/values, or a (block, 1)
+    per-row column (unit statistic, key word)."""
+    return pl.BlockSpec((_block_rows(R), cols), lambda i: (i, 0))
 
 
-# --------------------------------------------------------------------------
-# width-parametric field packing: (R, 512) int32 width-bit fields ->
-# (R, 16*width) uint32 words in ONE launch (no {0,1} bit intermediate —
-# each 32-field chunk becomes exactly `width` words with compile-time
-# shifts, see kernels/ref.pack_fields_tile). This is the single-launch
-# pack leg the natural (9-bit) and sparse-index (ceil(log2 d)-bit) codecs
-# use; qsgd/terngrad/sign fuse their quantizers in front of the same tile
-# packer (kernels/{qsgd,terngrad,sign}.py).
-# --------------------------------------------------------------------------
-
-def _fields_pack_kernel(f_ref, o_ref, *, width: int):
-    o_ref[...] = ref.pack_fields_tile(f_ref[...], width)
+def word_spec(R: int, width: int) -> pl.BlockSpec:
+    """The packed words of one block of tile rows."""
+    return pl.BlockSpec((_block_rows(R) // _WR, PACK_C // 4 * width),
+                        lambda i: (i, 0))
 
 
-def _fields_unpack_kernel(w_ref, o_ref, *, width: int):
-    o_ref[...] = ref.unpack_fields_tile(w_ref[...], width)
+def word_shape(R: int, width: int) -> jax.ShapeDtypeStruct:
+    return jax.ShapeDtypeStruct((R // _WR, PACK_C // 4 * width), jnp.uint32)
+
+
+def _fields_pack_kernel(f_ref, o_ref, *, width: int, tiled: bool):
+    o_ref[...] = ref.pack_fields_tile(f_ref[...], width, tiled=tiled)
+
+
+def _fields_unpack_kernel(w_ref, o_ref, *, width: int, tiled: bool):
+    o_ref[...] = ref.unpack_fields_tile(w_ref[...], width, tiled=tiled)
 
 
 def fields_pack_pallas(fields: jax.Array, width: int, *,
-                       interpret: bool = True) -> jax.Array:
-    """(R, 512) int32 fields (values < 2**width; R % PACK_R == 0) ->
-    (R, 16*width) uint32 words."""
+                       interpret: bool) -> jax.Array:
+    """(R, 512) int32 fields (values < 2**width; R == pack_tile_rows(R))
+    -> (R//8, 128*width) uint32 words."""
     R, C = fields.shape
-    assert R % PACK_R == 0 and C == PACK_C, (R, C)
-    wpr = (C // 32) * width
+    assert C == PACK_C, (R, C)
     return pl.pallas_call(
-        functools.partial(_fields_pack_kernel, width=width),
-        grid=(R // PACK_R,),
-        in_specs=[pl.BlockSpec((PACK_R, PACK_C), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((PACK_R, wpr), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, wpr), jnp.uint32),
+        functools.partial(_fields_pack_kernel, width=width,
+                          tiled=not interpret),
+        grid=pack_grid(R),
+        in_specs=[tile_spec(R)],
+        out_specs=word_spec(R, width),
+        out_shape=word_shape(R, width),
         interpret=interpret,
     )(fields)
 
 
 def fields_unpack_pallas(words: jax.Array, width: int, *,
-                         interpret: bool = True) -> jax.Array:
-    """(R, 16*width) uint32 -> (R, 512) int32 fields. Inverse of
+                         interpret: bool) -> jax.Array:
+    """(R//8, 128*width) uint32 -> (R, 512) int32 fields. Inverse of
     fields_pack_pallas."""
-    R, W = words.shape
-    wpr = (PACK_C // 32) * width
-    assert R % PACK_R == 0 and W == wpr, (R, W, width)
+    R = words.shape[0] * _WR
+    assert words.shape == word_shape(R, width).shape, (words.shape, width)
     return pl.pallas_call(
-        functools.partial(_fields_unpack_kernel, width=width),
-        grid=(R // PACK_R,),
-        in_specs=[pl.BlockSpec((PACK_R, wpr), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((PACK_R, PACK_C), lambda i: (i, 0)),
+        functools.partial(_fields_unpack_kernel, width=width,
+                          tiled=not interpret),
+        grid=pack_grid(R),
+        in_specs=[word_spec(R, width)],
+        out_specs=tile_spec(R),
         out_shape=jax.ShapeDtypeStruct((R, PACK_C), jnp.int32),
         interpret=interpret,
     )(words)

@@ -8,18 +8,18 @@ kernel body, but its threefry2x32 generator is 20 rounds of uint32
 add/xor/rotate — pure VPU work — so we reproduce it here as elementwise
 jnp ops usable both inside kernel bodies and as a jit-able oracle.
 
-`uniform_at(k0, k1, pos, n)` returns `jax.random.uniform(key, (n,))[pos]`
-BIT-exactly (tests/test_fused_kernels.py pins this against jax itself,
-so a jax upgrade that changes the generator fails loudly instead of
-silently corrupting payload identity). The positional form is what a
-tiled kernel needs: each (row, lane) knows its flat position inside the
-compression unit and evaluates only its own counter pair.
+`uniform_at(k0, k1, pos)` returns `jax.random.uniform(key, (n,))[pos]`
+BIT-exactly for any draw length n > pos (tests/test_fused_kernels.py pins
+this against jax itself, so a jax upgrade that changes the generator
+fails loudly instead of silently corrupting payload identity). The
+positional form is what a tiled kernel needs: each (row, lane) knows its
+flat position inside the compression unit and evaluates only its own
+counter.
 
-Counter layout (jax's non-partitionable threefry path): a length-n draw
-evaluates threefry2x32(key, [0..n-1] zero-padded to even length, split
-into half-arrays x1/x2), so position p < h := ceil(n/2) is output word 0
-of the pair (p, p+h) — with the odd-n pad folding the last x2 slot to 0
-— and position p >= h is output word 1 of the pair (p-h, p).
+Counter layout (jax's partitionable threefry path, the default since jax
+0.5): a draw evaluates threefry2x32(key, (hi, lo)) on the 64-bit flat
+index of each element split into two uint32 words, and XORs the two
+output words — so position p (< 2**32) is o1 ^ o2 of the pair (0, p).
 """
 from __future__ import annotations
 
@@ -53,20 +53,14 @@ def threefry2x32(k0: Array, k1: Array, x0: Array, x1: Array):
     return x0, x1
 
 
-def random_bits_at(k0: Array, k1: Array, pos: Array, n: int) -> Array:
+def random_bits_at(k0: Array, k1: Array, pos: Array) -> Array:
     """Bits of jax.random.bits(key, (n,))[pos] for uint32 keys (k0, k1).
 
-    `pos` int32/uint32, any shape (values >= n are computed but
-    meaningless — mask them downstream); `n` the static draw length.
-    """
+    `pos` int32/uint32, any shape (positions past a unit's end are
+    computed but meaningless — mask them downstream)."""
     p = pos.astype(jnp.uint32)
-    h = np.uint32((n + 1) // 2)
-    first = p < h
-    j = jnp.where(first, p, p - h)
-    # the odd-n zero pad occupies the last x2 slot
-    x2 = jnp.where(h + j < np.uint32(n), h + j, np.uint32(0))
-    o1, o2 = threefry2x32(k0, k1, j, x2)
-    return jnp.where(first, o1, o2)
+    o1, o2 = threefry2x32(k0, k1, jnp.zeros_like(p), p)
+    return o1 ^ o2
 
 
 def bits_to_uniform(bits: Array) -> Array:
@@ -77,6 +71,6 @@ def bits_to_uniform(bits: Array) -> Array:
     return jnp.maximum(jnp.float32(0.0), u)
 
 
-def uniform_at(k0: Array, k1: Array, pos: Array, n: int) -> Array:
+def uniform_at(k0: Array, k1: Array, pos: Array) -> Array:
     """jax.random.uniform(key, (n,))[pos], bit for bit, elementwise."""
-    return bits_to_uniform(random_bits_at(k0, k1, pos, n))
+    return bits_to_uniform(random_bits_at(k0, k1, pos))

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import prng, ref
-from repro.kernels.pack import PACK_R
+from repro.kernels.pack import pack_grid, tile_spec, word_shape, word_spec
 
 BLOCK_R = 256          # rows per grid step (multiple of 8)
 BLOCK_C = 512          # lane columns (multiple of 128)
@@ -65,7 +65,7 @@ def _qsgd_rows_kernel(x_ref, u_ref, norm_ref, o_ref, *, levels: int):
 
 
 def qsgd_pallas_rows(x: jax.Array, noise: jax.Array, norms: jax.Array,
-                     levels: int, *, interpret: bool = True) -> jax.Array:
+                     levels: int, *, interpret: bool) -> jax.Array:
     """Per-ROW-scale QSGD: one fused dispatch for a whole UnitPlan bucket.
 
     x, noise: (R, C) f32 with R % BLOCK_R == 0, C == BLOCK_C; norms:
@@ -105,73 +105,64 @@ def _row_positions(block_shape, rpu: int):
 
 
 def _qsgd_pack_kernel(x_ref, k0_ref, k1_ref, nrm_ref, o_ref, *,
-                      levels: int, width: int, d: int, rpu: int):
+                      levels: int, width: int, d: int, rpu: int, tiled: bool):
     x = x_ref[...]                                   # (R, 512) f32
     pos = _row_positions(x.shape, rpu)
-    u = prng.uniform_at(k0_ref[...], k1_ref[...], pos, d)
+    u = prng.uniform_at(k0_ref[...], k1_ref[...], pos)
     codes = ref.qsgd_codes_ref(x, u, nrm_ref[...], levels)
     codes = jnp.where(pos < d, codes, 0)             # zero word padding
-    o_ref[...] = ref.pack_fields_tile(codes, width)
+    o_ref[...] = ref.pack_fields_tile(codes, width, tiled=tiled)
 
 
-def _qsgd_unpack_kernel(w_ref, fac_ref, o_ref, *, levels: int, width: int):
-    codes = ref.unpack_fields_tile(w_ref[...], width)
+def _qsgd_unpack_kernel(w_ref, fac_ref, o_ref, *, levels: int, width: int,
+                        tiled: bool):
+    codes = ref.unpack_fields_tile(w_ref[...], width, tiled=tiled)
     o_ref[...] = ref.qsgd_decode_ref(codes, fac_ref[...], levels)
 
 
 def qsgd_pack_pallas_rows(x: jax.Array, k0: jax.Array, k1: jax.Array,
                           nrms: jax.Array, levels: int, width: int, *,
-                          d: int, rpu: int,
-                          interpret: bool = True) -> jax.Array:
+                          d: int, rpu: int, interpret: bool) -> jax.Array:
     """Fused quantize+pack over a bucket tile: x (R, 512) f32 with
-    R % PACK_R == 0 (units of dim `d` spanning `rpu` rows each), per-row
-    threefry key columns k0/k1 (R, 1) uint32 and unit norms nrms (R, 1)
-    f32 (+1e-12 already added) -> (R, 16*width) uint32 payload words.
-    ONE launch, 1 f32 read + 1 packed-word write per element."""
+    R == pack_tile_rows(R) (units of dim `d` spanning `rpu` rows each),
+    per-row threefry key columns k0/k1 (R, 1) uint32 and unit norms nrms
+    (R, 1) f32 (+1e-12 already added) -> (R//8, 128*width) uint32 payload
+    words. ONE launch, 1 f32 read + 1 packed-word write per element."""
     R, C = x.shape
-    assert R % PACK_R == 0 and C == BLOCK_C, (R, C)
+    assert C == BLOCK_C, (R, C)
     assert k0.shape == k1.shape == nrms.shape == (R, 1)
-    wpr = (C // 32) * width
+    col = tile_spec(R, 1)
     return pl.pallas_call(
         functools.partial(_qsgd_pack_kernel, levels=levels, width=width,
-                          d=d, rpu=rpu),
-        grid=(R // PACK_R,),
-        in_specs=[
-            pl.BlockSpec((PACK_R, C), lambda i: (i, 0)),
-            pl.BlockSpec((PACK_R, 1), lambda i: (i, 0)),
-            pl.BlockSpec((PACK_R, 1), lambda i: (i, 0)),
-            pl.BlockSpec((PACK_R, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((PACK_R, wpr), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, wpr), jnp.uint32),
+                          d=d, rpu=rpu, tiled=not interpret),
+        grid=pack_grid(R),
+        in_specs=[tile_spec(R), col, col, col],
+        out_specs=word_spec(R, width),
+        out_shape=word_shape(R, width),
         interpret=interpret,
     )(x, k0, k1, nrms)
 
 
 def qsgd_unpack_pallas_rows(words: jax.Array, facs: jax.Array, levels: int,
-                            width: int, *,
-                            interpret: bool = True) -> jax.Array:
-    """Fused unpack+dequantize: words (R, 16*width) uint32 + per-row
+                            width: int, *, interpret: bool) -> jax.Array:
+    """Fused unpack+dequantize: words (R//8, 128*width) uint32 + per-row
     dequant factors facs = norm/levels (R, 1), division done by the
     CALLER (see ref.qsgd_decode_ref) -> (R, 512) f32."""
-    R, W = words.shape
-    wpr = (BLOCK_C // 32) * width
-    assert R % PACK_R == 0 and W == wpr, (R, W, width)
+    R = facs.shape[0]
+    assert words.shape == word_shape(R, width).shape, (words.shape, width)
     return pl.pallas_call(
-        functools.partial(_qsgd_unpack_kernel, levels=levels, width=width),
-        grid=(R // PACK_R,),
-        in_specs=[
-            pl.BlockSpec((PACK_R, wpr), lambda i: (i, 0)),
-            pl.BlockSpec((PACK_R, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((PACK_R, BLOCK_C), lambda i: (i, 0)),
+        functools.partial(_qsgd_unpack_kernel, levels=levels, width=width,
+                          tiled=not interpret),
+        grid=pack_grid(R),
+        in_specs=[word_spec(R, width), tile_spec(R, 1)],
+        out_specs=tile_spec(R),
         out_shape=jax.ShapeDtypeStruct((R, BLOCK_C), jnp.float32),
         interpret=interpret,
     )(words, facs)
 
 
 def qsgd_pallas(x: jax.Array, noise: jax.Array, norm: jax.Array,
-                levels: int, *, interpret: bool = True) -> jax.Array:
+                levels: int, *, interpret: bool) -> jax.Array:
     """x, noise: (R, C) f32 with R % BLOCK_R == 0, C == BLOCK_C.
     norm: () f32. interpret=True runs the kernel body on CPU (validation);
     on TPU pass interpret=False."""

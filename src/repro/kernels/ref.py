@@ -110,47 +110,94 @@ def sign_decode_ref(codes: Array) -> Array:
 # uint32 words, with compile-time shift constants. Every 32-field chunk
 # spans 32*width bits == width whole words, so ANY width packs without
 # cross-chunk straddle — the core trick that removes the {0,1} bit-tensor
-# (a 32x memory inflation) from both the jnp fallback and the kernels.
-# Shared by the Pallas kernel bodies (pure jnp => identical arithmetic).
+# (a 32x memory inflation) from both the jnp twins and the kernels.
+#
+# Layout: eight 512-field tile rows pack into one row of 128*width words,
+# so both sides of the pack keep a lane-dense minor dimension (a multiple
+# of 128) and neither Mosaic nor XLA pads a narrow one. The 32 fields of a
+# word are brought onto the SUBLANE axis by 128x128 transposes (each
+# 128-lane slice of a tile row is 4 chunks of 32 fields); the shifts and
+# ORs then combine whole rows, and one transpose puts the words back on
+# lanes. Shared by the Pallas kernel bodies and the jnp twins (pure
+# jnp => identical arithmetic and identical payloads); only the transpose
+# differs: Mosaic lowers one 2-D transpose per 128x128 tile (`tiled`,
+# what the compiled kernels run), while XLA — the jnp twins and the
+# interpreted kernel bodies on CPU — takes the whole tile in one 4-D
+# transpose (far fewer ops to compile). tests/test_fused_kernels.py
+# holds the two equal.
 # --------------------------------------------------------------------------
 
-def pack_fields_tile(fields: Array, width: int) -> Array:
-    """(R, C) int32 fields with C % 32 == 0, values < 2**width ->
-    (R, C*width//32) uint32 words (little-endian bit order: field i's low
-    bit lands at bit-stream position i*width)."""
-    R, C = fields.shape
-    nc = C // 32
-    v = fields.reshape(R, nc, 32).astype(jnp.uint32)
-    words = []
+#: tile rows whose fields fill one row of packed words
+ROWS_PER_WORD_ROW = 8
+
+
+def _word_pieces(width: int):
+    """Per word t of a 32-field chunk: the (field j, shift) pairs landing
+    in it (shift < 0: the field's low bits spill from word t-1)."""
+    spans = []
     for t in range(width):
-        w = jnp.zeros((R, nc), jnp.uint32)
-        for j in range(32):
-            lo, hi = j * width, (j + 1) * width      # field j's bit span
-            if hi <= 32 * t or lo >= 32 * (t + 1):   # no overlap w/ word t
-                continue
-            s = lo - 32 * t
-            f = v[:, :, j]
-            w = w | (f << jnp.uint32(s) if s >= 0 else f >> jnp.uint32(-s))
+        spans.append([(j, j * width - 32 * t) for j in range(32)
+                      if (j + 1) * width > 32 * t
+                      and j * width < 32 * (t + 1)])
+    return spans
+
+
+def pack_fields_tile(fields: Array, width: int, tiled: bool = False
+                     ) -> Array:
+    """(R, 512) int32 fields with R % 8 == 0, values < 2**width ->
+    (R//8, 128*width) uint32 words. Read row-major, the words are the
+    little-endian bit stream of the fields read row-major: field i's low
+    bit lands at bit-stream position i*width."""
+    R, C = fields.shape
+    a = R // ROWS_PER_WORD_ROW
+    v = fields.astype(jnp.uint32).reshape(a, ROWS_PER_WORD_ROW, C)
+    # (sub-tile, chunk) on axis 0, field-in-chunk j on axis 1, a on lanes
+    if tiled:
+        subs = [v[:, k, m:m + 128].T
+                for k in range(ROWS_PER_WORD_ROW) for m in range(0, C, 128)]
+        t = jnp.stack(subs).reshape(-1, 32, a)
+    else:
+        t = v.reshape(a, ROWS_PER_WORD_ROW, C // 128, 128).transpose(
+            1, 2, 3, 0).reshape(-1, 32, a)
+    words = []
+    for pieces in _word_pieces(width):
+        w = None
+        for j, s in pieces:
+            f = t[:, j, :]
+            f = f << jnp.uint32(s) if s >= 0 else f >> jnp.uint32(-s)
+            w = f if w is None else w | f
         words.append(w)
-    return jnp.stack(words, axis=2).reshape(R, nc * width)
+    # rows (chunk, word-in-chunk) are the stream order; lanes back to a
+    return jnp.stack(words, axis=1).reshape(-1, a).T
 
 
-def unpack_fields_tile(words: Array, width: int) -> Array:
-    """(R, nc*width) uint32 words -> (R, nc*32) int32 fields. Inverse of
+def unpack_fields_tile(words: Array, width: int, tiled: bool = False
+                       ) -> Array:
+    """(A, 128*width) uint32 words -> (8*A, 512) int32 fields. Inverse of
     pack_fields_tile."""
-    R, W = words.shape
-    nc = W // width
-    v = words.reshape(R, nc, width)
+    a, W = words.shape
+    n_chunks = W // width
+    z = words.T.reshape(n_chunks, width, a)
     mask = jnp.uint32((1 << width) - 1)
     fields = []
     for j in range(32):
         lo = j * width
         t0, s = lo // 32, lo % 32
-        f = v[:, :, t0] >> jnp.uint32(s)
+        f = z[:, t0, :] >> jnp.uint32(s)
         if lo + width > 32 * (t0 + 1):               # straddles into t0+1
-            f = f | (v[:, :, t0 + 1] << jnp.uint32(32 - s))
+            f = f | (z[:, t0 + 1, :] << jnp.uint32(32 - s))
         fields.append(f & mask)
-    return jnp.stack(fields, axis=2).reshape(R, nc * 32).astype(jnp.int32)
+    f = jnp.stack(fields, axis=1).reshape(n_chunks // 4, 128, a)
+    per_k = n_chunks // 4 // ROWS_PER_WORD_ROW       # 128-lane groups per row
+    if not tiled:
+        return f.reshape(ROWS_PER_WORD_ROW, per_k, 128, a).transpose(
+            3, 0, 1, 2).reshape(ROWS_PER_WORD_ROW * a, per_k * 128).astype(
+                jnp.int32)
+    subs = [f[i].T for i in range(n_chunks // 4)]    # (a, 128) each
+    rows = [jnp.concatenate(subs[k * per_k:(k + 1) * per_k], axis=1)
+            for k in range(ROWS_PER_WORD_ROW)]
+    return jnp.stack(rows, axis=1).reshape(
+        ROWS_PER_WORD_ROW * a, per_k * 128).astype(jnp.int32)
 
 
 def pack_fields_bitexpand_ref(vals: Array, width: int) -> Array:

@@ -19,7 +19,7 @@ def _rmsnorm_kernel(x_ref, g_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_pallas(x: jax.Array, gamma: jax.Array, eps: float = 1e-5,
-                   *, interpret: bool = True) -> jax.Array:
+                   *, interpret: bool) -> jax.Array:
     """x (R, D) rows normalized over D (D multiple of 128)."""
     R, D = x.shape
     assert R % BLOCK_R == 0 and D % 128 == 0, (R, D)

@@ -23,22 +23,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import ref
-from repro.kernels.pack import PACK_R
+from repro.kernels.pack import pack_grid, tile_spec, word_shape, word_spec
 
 BLOCK_C = 512
 MAJ_C = 512            # majority-vote word columns per grid step
 
 
-def _sign_pack_kernel(x_ref, o_ref, *, d: int, rpu: int):
+def _sign_pack_kernel(x_ref, o_ref, *, d: int, rpu: int, tiled: bool):
     from repro.kernels.qsgd import _row_positions
     x = x_ref[...]                                   # (R, 512) f32
     pos = _row_positions(x.shape, rpu)
     codes = jnp.where(pos < d, ref.sign_codes_ref(x), 0)
-    o_ref[...] = ref.pack_fields_tile(codes, 1)
+    o_ref[...] = ref.pack_fields_tile(codes, 1, tiled=tiled)
 
 
-def _sign_unpack_kernel(w_ref, o_ref):
-    codes = ref.unpack_fields_tile(w_ref[...], 1)
+def _sign_unpack_kernel(w_ref, o_ref, *, tiled: bool):
+    codes = ref.unpack_fields_tile(w_ref[...], 1, tiled=tiled)
     o_ref[...] = ref.sign_decode_ref(codes)
 
 
@@ -47,41 +47,41 @@ def _majority_kernel(w_ref, o_ref):
 
 
 def sign_pack_pallas_rows(x: jax.Array, *, d: int, rpu: int,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool) -> jax.Array:
     """Fused sign+pack over a bucket tile: x (R, 512) f32 with
-    R % PACK_R == 0 (units of dim `d` spanning `rpu` rows each) ->
-    (R, 16) uint32 sign words. ONE launch, no noise, no statistic."""
+    R == pack_tile_rows(R) (units of dim `d` spanning `rpu` rows each) ->
+    (R//8, 128) uint32 sign words. ONE launch, no noise, no statistic."""
     R, C = x.shape
-    assert R % PACK_R == 0 and C == BLOCK_C, (R, C)
-    wpr = C // 32
+    assert C == BLOCK_C, (R, C)
     return pl.pallas_call(
-        functools.partial(_sign_pack_kernel, d=d, rpu=rpu),
-        grid=(R // PACK_R,),
-        in_specs=[pl.BlockSpec((PACK_R, C), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((PACK_R, wpr), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, wpr), jnp.uint32),
+        functools.partial(_sign_pack_kernel, d=d, rpu=rpu,
+                          tiled=not interpret),
+        grid=pack_grid(R),
+        in_specs=[tile_spec(R)],
+        out_specs=word_spec(R, 1),
+        out_shape=word_shape(R, 1),
         interpret=interpret,
     )(x)
 
 
 def sign_unpack_pallas_rows(words: jax.Array, *,
-                            interpret: bool = True) -> jax.Array:
-    """Fused unpack+decode: words (R, 16) uint32 -> (R, 512) f32 signs."""
-    R, W = words.shape
-    wpr = BLOCK_C // 32
-    assert R % PACK_R == 0 and W == wpr, (R, W)
+                            interpret: bool) -> jax.Array:
+    """Fused unpack+decode: words (R//8, 128) uint32 -> (R, 512) f32
+    signs."""
+    R = words.shape[0] * 8
+    assert words.shape == word_shape(R, 1).shape, words.shape
     return pl.pallas_call(
-        _sign_unpack_kernel,
-        grid=(R // PACK_R,),
-        in_specs=[pl.BlockSpec((PACK_R, wpr), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((PACK_R, BLOCK_C), lambda i: (i, 0)),
+        functools.partial(_sign_unpack_kernel, tiled=not interpret),
+        grid=pack_grid(R),
+        in_specs=[word_spec(R, 1)],
+        out_specs=tile_spec(R),
         out_shape=jax.ShapeDtypeStruct((R, BLOCK_C), jnp.float32),
         interpret=interpret,
     )(words)
 
 
 def majority_pallas(words: jax.Array, *,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """(n_workers, W) uint32 packed sign words with W % MAJ_C == 0 ->
     (W,) majority words, never unpacking to bits. Zero-padded word
     columns vote 0 everywhere and are truncated by the caller."""

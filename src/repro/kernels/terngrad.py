@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import prng, ref
-from repro.kernels.pack import PACK_R
+from repro.kernels.pack import pack_grid, tile_spec, word_shape, word_spec
 
 BLOCK_R = 256
 BLOCK_C = 512
@@ -46,7 +46,7 @@ def _terngrad_rows_kernel(x_ref, u_ref, scale_ref, o_ref):
 
 
 def terngrad_pallas_rows(x: jax.Array, noise: jax.Array, scales: jax.Array,
-                         *, interpret: bool = True) -> jax.Array:
+                         *, interpret: bool) -> jax.Array:
     """Per-ROW-scale TernGrad: one fused dispatch for a whole UnitPlan
     bucket. scales: (R, 1) — max|x| of the unit each tile row belongs to."""
     R, C = x.shape
@@ -74,69 +74,61 @@ TERN_WIDTH = 2
 
 
 def _tern_pack_kernel(x_ref, k0_ref, k1_ref, s_ref, o_ref, *,
-                      d: int, rpu: int):
+                      d: int, rpu: int, tiled: bool):
     from repro.kernels.qsgd import _row_positions
     x = x_ref[...]                                   # (R, 512) f32
     pos = _row_positions(x.shape, rpu)
-    u = prng.uniform_at(k0_ref[...], k1_ref[...], pos, d)
+    u = prng.uniform_at(k0_ref[...], k1_ref[...], pos)
     codes = ref.terngrad_codes_ref(x, u, s_ref[...])
     codes = jnp.where(pos < d, codes, 0)             # zero word padding
-    o_ref[...] = ref.pack_fields_tile(codes, TERN_WIDTH)
+    o_ref[...] = ref.pack_fields_tile(codes, TERN_WIDTH, tiled=tiled)
 
 
-def _tern_unpack_kernel(w_ref, s_ref, o_ref):
-    codes = ref.unpack_fields_tile(w_ref[...], TERN_WIDTH)
+def _tern_unpack_kernel(w_ref, s_ref, o_ref, *, tiled: bool):
+    codes = ref.unpack_fields_tile(w_ref[...], TERN_WIDTH, tiled=tiled)
     o_ref[...] = ref.terngrad_decode_ref(codes, s_ref[...])
 
 
 def terngrad_pack_pallas_rows(x: jax.Array, k0: jax.Array, k1: jax.Array,
                               scales: jax.Array, *, d: int, rpu: int,
-                              interpret: bool = True) -> jax.Array:
+                              interpret: bool) -> jax.Array:
     """Fused ternarize+pack over a bucket tile: x (R, 512) f32 with
-    R % PACK_R == 0, per-row threefry key columns k0/k1 (R, 1) uint32 and
-    unit scales (max|x| + 1e-12 already added) scales (R, 1) f32 ->
-    (R, 32) uint32 payload words. ONE launch."""
+    R == pack_tile_rows(R), per-row threefry key columns k0/k1 (R, 1)
+    uint32 and unit scales (max|x| + 1e-12 already added) scales (R, 1)
+    f32 -> (R//8, 256) uint32 payload words. ONE launch."""
     R, C = x.shape
-    assert R % PACK_R == 0 and C == BLOCK_C, (R, C)
+    assert C == BLOCK_C, (R, C)
     assert k0.shape == k1.shape == scales.shape == (R, 1)
-    wpr = (C // 32) * TERN_WIDTH
+    col = tile_spec(R, 1)
     return pl.pallas_call(
-        functools.partial(_tern_pack_kernel, d=d, rpu=rpu),
-        grid=(R // PACK_R,),
-        in_specs=[
-            pl.BlockSpec((PACK_R, C), lambda i: (i, 0)),
-            pl.BlockSpec((PACK_R, 1), lambda i: (i, 0)),
-            pl.BlockSpec((PACK_R, 1), lambda i: (i, 0)),
-            pl.BlockSpec((PACK_R, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((PACK_R, wpr), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, wpr), jnp.uint32),
+        functools.partial(_tern_pack_kernel, d=d, rpu=rpu,
+                          tiled=not interpret),
+        grid=pack_grid(R),
+        in_specs=[tile_spec(R), col, col, col],
+        out_specs=word_spec(R, TERN_WIDTH),
+        out_shape=word_shape(R, TERN_WIDTH),
         interpret=interpret,
     )(x, k0, k1, scales)
 
 
 def terngrad_unpack_pallas_rows(words: jax.Array, scales: jax.Array, *,
-                                interpret: bool = True) -> jax.Array:
-    """Fused unpack+dequantize: words (R, 32) uint32 + per-row payload
+                                interpret: bool) -> jax.Array:
+    """Fused unpack+dequantize: words (R//8, 256) uint32 + per-row payload
     scales (R, 1) -> (R, 512) f32."""
-    R, W = words.shape
-    wpr = (BLOCK_C // 32) * TERN_WIDTH
-    assert R % PACK_R == 0 and W == wpr, (R, W)
+    R = scales.shape[0]
+    assert words.shape == word_shape(R, TERN_WIDTH).shape, words.shape
     return pl.pallas_call(
-        _tern_unpack_kernel,
-        grid=(R // PACK_R,),
-        in_specs=[
-            pl.BlockSpec((PACK_R, wpr), lambda i: (i, 0)),
-            pl.BlockSpec((PACK_R, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((PACK_R, BLOCK_C), lambda i: (i, 0)),
+        functools.partial(_tern_unpack_kernel, tiled=not interpret),
+        grid=pack_grid(R),
+        in_specs=[word_spec(R, TERN_WIDTH), tile_spec(R, 1)],
+        out_specs=tile_spec(R),
         out_shape=jax.ShapeDtypeStruct((R, BLOCK_C), jnp.float32),
         interpret=interpret,
     )(words, scales)
 
 
 def terngrad_pallas(x: jax.Array, noise: jax.Array, scale: jax.Array,
-                    *, interpret: bool = True) -> jax.Array:
+                    *, interpret: bool) -> jax.Array:
     R, C = x.shape
     assert R % BLOCK_R == 0 and C == BLOCK_C, (R, C)
     return pl.pallas_call(

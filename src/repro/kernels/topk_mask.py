@@ -40,7 +40,7 @@ def _topk_kernel(x_ref, o_ref, *, k: int):
     o_ref[...] = x * (mag >= lo).astype(x.dtype)
 
 
-def topk_mask_pallas(x: jax.Array, k: int, *, interpret: bool = True
+def topk_mask_pallas(x: jax.Array, k: int, *, interpret: bool
                      ) -> jax.Array:
     """x (R, C): per-row top-k mask. R % BLOCK_R == 0, C == BLOCK_C."""
     R, C = x.shape

@@ -123,8 +123,6 @@ def analyze_compiled(compiled, *, arch: str, shape, mesh_name: str,
                      chips: int, cfg) -> Roofline:
     from repro.launch.hlo_cost import scan_scaled_costs
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0]
     raw = {"flops": float(cost.get("flops", 0.0)),
            "bytes_accessed": float(cost.get("bytes accessed", 0.0))}
     mem = {}

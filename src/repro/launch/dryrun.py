@@ -77,8 +77,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, comp, opt,
         mesh = make_production_mesh(multi_pod=multi_pod)
     chips = mesh.devices.size
     eng = Engine(cfg, mesh, comp=comp, opt=opt, remat=remat)
-    with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \
-            else mesh:
+    with jax.sharding.use_mesh(mesh):
         if shape.kind == "train":
             step = eng.build_train_step()
             args_sds, _ = eng.train_input_specs(shape)

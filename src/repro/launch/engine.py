@@ -19,18 +19,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
 
 from repro.core.aggregation import CompressionConfig, compressed_allreduce
 from repro.core.granularity import Granularity
@@ -571,7 +565,15 @@ class Engine:
         return est
 
     def init_state(self, seed: int = 0):
-        """Materialize params + optimizer state (small meshes / smoke)."""
+        """Materialize params + optimizer state, placed on the mesh as the
+        train step returns them (so the second step reuses the first
+        step's compiled program)."""
         params = self.model.init(jax.random.key(seed))
         opt_state = init_opt_state(self.opt, params)
-        return params, opt_state
+
+        def place(tree, specs):
+            return jax.tree_util.tree_map(
+                lambda x, p: jax.device_put(x, NamedSharding(self.mesh, p)),
+                tree, specs)
+        return (place(params, self.model.param_pspecs()),
+                place(opt_state, self._opt_pspecs()))

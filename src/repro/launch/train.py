@@ -11,8 +11,11 @@ Example:
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
 import sys
 import time
+from typing import Any, Iterator, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +28,25 @@ from repro.launch.engine import Engine
 from repro.launch.mesh import make_host_mesh
 from repro.ckpt import latest_checkpoint, load_checkpoint, save_checkpoint
 from repro.optim import OptConfig, piecewise_linear
+
+
+#: root of the checkout this module runs from (src/repro/launch/ -> ../../..)
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def compile_cache_dir() -> str:
+    """Where compiled programs are kept across processes:
+    $JAX_COMPILATION_CACHE_DIR where it is set, else a fixed directory
+    inside the checkout (a fixed path, so a later run hits the cache)."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(_CHECKOUT, ".jax_cache"))
+
+
+def enable_compile_cache() -> None:
+    """Turn on jax's persistent compilation cache at compile_cache_dir().
+    Call before the process compiles anything."""
+    jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
 
 
 def build_controller(args, eng, sched, *, metrics=None, tracer=None):
@@ -60,7 +82,8 @@ def build_compression(args) -> CompressionConfig:
         fusion_bytes=args.fusion_bytes)
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
+    """The launcher's command line -> validated args."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="mamba2-1.3b", choices=ARCH_NAMES)
     ap.add_argument("--smoke", action="store_true",
@@ -148,13 +171,6 @@ def main(argv=None):
         ap.error("--resume restores from --ckpt-dir; set it")
     if args.telemetry_out and not args.policy:
         args.policy = "static"  # telemetry collection needs the controller
-
-    cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
-    mesh = make_host_mesh(data=args.data, model=args.model)
-    comp = build_compression(args)
-    opt = OptConfig(name=args.optimizer, lr=args.lr, nesterov=args.nesterov)
-    eng = Engine(cfg, mesh, comp=comp, opt=opt)
-    sched = piecewise_linear(args.lr, args.steps, max(1, args.steps // 10))
     if args.wire and args.policy:
         ap.error("--wire is the static engine path; drop --policy")
     if args.step_guard and args.policy:
@@ -162,9 +178,70 @@ def main(argv=None):
     if args.collective and not args.wire:
         ap.error("--collective picks the wire collective's topology; "
                  "add --wire")
-    if args.collective and comp.strategy == "dense":
+    if args.collective and args.compressor == "none":
         ap.error("--collective needs a compressor (the dense path has no "
                  "wire messages to stream); add --compressor")
+    return args
+
+
+@dataclasses.dataclass
+class Job:
+    """A launched run before its first step: the engine, the jitted step
+    (or the controller that builds steps), the initial state and the
+    batch source."""
+    args: argparse.Namespace
+    cfg: Any
+    mesh: Any
+    eng: Engine
+    sched: Any
+    step_fn: Any
+    ctrl: Any
+    rec: Any
+    reg: Any
+    params: Any
+    opt_state: Any
+    start: int
+
+    def batches(self) -> Iterator[Tuple[int, dict]]:
+        """(step, batch) from the resume point on — the exact stream an
+        uninterrupted run sees."""
+        args, cfg = self.args, self.cfg
+        it = lm_batches(cfg.vocab, args.batch, args.seq, seed=args.seed)
+        for _ in range(self.start):
+            next(it)
+        key = jax.random.key(args.seed)
+        for i in range(self.start, args.steps):
+            batch = next(it)
+            if cfg.arch_type == "vlm":
+                batch["patch_embeds"] = patches_stub(
+                    jax.random.fold_in(key, i), args.batch,
+                    cfg.frontend_seq, cfg.d_model)
+            if cfg.arch_type == "audio":
+                batch["frames"] = frames_stub(
+                    jax.random.fold_in(key, i), args.batch,
+                    cfg.frontend_seq, cfg.d_model)
+            yield i, batch
+
+
+@dataclasses.dataclass
+class Result:
+    """What a run leaves: every step's loss, the wall clock at each
+    progress line as (step, seconds since the first step began — the
+    line reads the loss, so the step has finished), and the final state."""
+    losses: List[float]
+    clock: List[Tuple[int, float]]
+    params: Any
+    opt_state: Any
+
+
+def prepare(args: argparse.Namespace) -> Job:
+    """Build the engine, the step and the initial (or resumed) state."""
+    cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
+    mesh = make_host_mesh(data=args.data, model=args.model)
+    comp = build_compression(args)
+    opt = OptConfig(name=args.optimizer, lr=args.lr, nesterov=args.nesterov)
+    eng = Engine(cfg, mesh, comp=comp, opt=opt)
+    sched = piecewise_linear(args.lr, args.steps, max(1, args.steps // 10))
     rec = reg = None
     if args.trace_out or args.metrics_out:
         from repro.obs import MetricsRegistry, TraceRecorder
@@ -220,22 +297,18 @@ def main(argv=None):
               f"(overlap {rep['model']['overlap_frac']:.0%}; model, not "
               f"measurement — trust the message counts)")
 
-    it = lm_batches(cfg.vocab, args.batch, args.seq, seed=args.seed)
-    for _ in range(start):   # replay the stream to the resume point: the
-        next(it)             # resumed run sees the exact batches the
-    key = jax.random.key(args.seed)  # uninterrupted run would have
-    with mesh:
+    return Job(args, cfg, mesh, eng, sched, step_fn, ctrl, rec, reg,
+               params, opt_state, start)
+
+
+def train(job: Job) -> Result:
+    """Run the job's steps; print progress, write the requested exports."""
+    args, ctrl, rec, reg = job.args, job.ctrl, job.rec, job.reg
+    params, opt_state = job.params, job.opt_state
+    losses, clock = [], []
+    with job.mesh:
         t0 = time.time()
-        for i in range(start, args.steps):
-            batch = next(it)
-            if cfg.arch_type == "vlm":
-                batch["patch_embeds"] = patches_stub(
-                    jax.random.fold_in(key, i), args.batch,
-                    cfg.frontend_seq, cfg.d_model)
-            if cfg.arch_type == "audio":
-                batch["frames"] = frames_stub(
-                    jax.random.fold_in(key, i), args.batch,
-                    cfg.frontend_seq, cfg.d_model)
+        for i, batch in job.batches():
             if ctrl is not None:
                 fn = ctrl.step_fn()
                 if ctrl.collect:
@@ -250,8 +323,9 @@ def main(argv=None):
                     print(f"step {i:5d} replan -> "
                           f"{ctrl.decision.describe()}")
             else:
-                params, opt_state, m = step_fn(params, opt_state, batch,
-                                               jnp.int32(i))
+                params, opt_state, m = job.step_fn(params, opt_state, batch,
+                                                   jnp.int32(i))
+            losses.append(m["loss"])
             if rec is not None:
                 # span stamps arrive via host callbacks — close the step
                 # before cutting it (honest timings, serialized steps)
@@ -263,9 +337,11 @@ def main(argv=None):
                     reg.inc("resil/steps_skipped", float(m["skipped"]))
                 reg.record(step=i)
             if i % max(1, args.steps // 20) == 0 or i == args.steps - 1:
-                print(f"step {i:5d} loss {float(m['loss']):.4f} "
+                loss = float(m["loss"])
+                clock.append((i, time.time() - t0))
+                print(f"step {i:5d} loss {loss:.4f} "
                       f"lr {float(m['lr']):.4f} "
-                      f"({time.time()-t0:.1f}s)")
+                      f"({clock[-1][1]:.1f}s)")
             if args.ckpt_dir and args.ckpt_every and \
                     (i + 1) % args.ckpt_every == 0:
                 save_checkpoint(args.ckpt_dir, i + 1,
@@ -288,6 +364,12 @@ def main(argv=None):
             ctrl.check_retraces()  # stamp the final retrace gauge
         n_lines = reg.export_jsonl(args.metrics_out)
         print(f"metrics -> {args.metrics_out} ({n_lines} lines)")
+    return Result([float(v) for v in losses], clock, params, opt_state)
+
+
+def main(argv=None) -> int:
+    enable_compile_cache()
+    train(prepare(parse_args(argv)))
     return 0
 
 

@@ -32,33 +32,6 @@ def _ceil_to(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-# jax 0.4.x ships optimization_barrier with no batching rule; the shared
-# shim lives in core.schedule (the other barrier user) — idempotent, so
-# calling it again here keeps this module import-order independent.
-from repro.core.schedule import register_barrier_batching_rule
-
-register_barrier_batching_rule()
-
-
-@jax.custom_vjp
-def _grad_barrier(x):
-    """optimization_barrier with a defined gradient (jax 0.4.x has no
-    differentiation rule for the primitive). The cotangent is barriered
-    too, preserving the hoisting protection in the backward scan."""
-    return jax.lax.optimization_barrier(x)
-
-
-def _grad_barrier_fwd(x):
-    return jax.lax.optimization_barrier(x), None
-
-
-def _grad_barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-_grad_barrier.defvjp(_grad_barrier_fwd, _grad_barrier_bwd)
-
-
 # ==========================================================================
 # parameter declaration
 # ==========================================================================
@@ -444,7 +417,7 @@ class Model:
             p_layer, kb, idx = xs
             # barrier: stops XLA from hoisting a convert of the whole saved
             # residual stack to f32 outside the backward loop (0.5 GB/layer)
-            x = _grad_barrier(x)
+            x = jax.lax.optimization_barrier(x)
             x, aux_l, cache = apply(p_layer, x, kb, idx)
             return (x, aux + aux_l), cache
 
@@ -473,7 +446,7 @@ class Model:
 
             def apply(p_group, x):
                 def inner(carry2, xs2):
-                    x2 = _grad_barrier(carry2)
+                    x2 = jax.lax.optimization_barrier(carry2)
                     p_layer, j = xs2
                     g = self._gather_layer(p_layer, meta_b, kb, comp)
                     h = apply_norm(g, "norm_in", x2, cfg, dist)

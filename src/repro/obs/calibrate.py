@@ -318,7 +318,7 @@ def fit_alpha_beta(samples: Sequence[Tuple[float, float]],
     rms = (math.sqrt(sum(r * r for r in resid) / len(resid))
            if resid else 0.0)
     return {"alpha_us": round(alpha, 3),
-            "gbps": round(gbps, 3) if gbps is not None else None,
+            "gbps": float(f"{gbps:.4g}") if gbps is not None else None,
             "us_per_byte": round(slope, 6),
             "n_samples": n,
             "resid_rms_us": round(rms, 3),

@@ -87,20 +87,14 @@ def test_roofline_bottleneck_classification():
 
 
 def test_collectives_detected_in_shardmap_hlo():
-    try:
-        from jax import shard_map as sm
-        kw = {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        kw = {"check_rep": False}
     mesh = jax.make_mesh((1,), ("data",))
     from jax.sharding import PartitionSpec as P
 
     def f(x):
         return jax.lax.psum(x, "data")
 
-    c = jax.jit(sm(f, mesh=mesh, in_specs=(P("data"),),
-                   out_specs=P(None), **kw)).lower(
+    c = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("data"),),
+                              out_specs=P(None), check_vma=False)).lower(
         jax.ShapeDtypeStruct((8,), jnp.float32)).compile()
     r = scan_scaled_costs(c.as_text(), 1)
     # group size 1 -> zero wire cost, but parse must not crash

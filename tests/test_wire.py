@@ -369,33 +369,35 @@ def test_signsgd_majority_vote_on_packed_words():
 
 
 def test_pallas_pack_kernels_match_oracle():
-    """kernels/pack.py vs kernels/ref.py: bit-for-bit, both directions,
-    and the ops wrappers' pallas/jnp paths agree on odd lengths."""
+    """kernels/pack.py at width 1 vs the kernels/ref.py bit oracle:
+    bit-for-bit, both directions, and the ops wrappers' pallas/jnp paths
+    agree on odd lengths."""
     from repro.kernels import ops
-    from repro.kernels.pack import pack_bits_pallas, unpack_bits_pallas
+    from repro.kernels.pack import fields_pack_pallas, fields_unpack_pallas
     from repro.kernels.ref import pack_bits_ref, unpack_bits_ref
-    bits = jax.random.bernoulli(KEY, 0.4, (16, 512)).astype(jnp.int32)
-    w_ref = pack_bits_ref(bits)
-    w_pal = pack_bits_pallas(bits, interpret=True)
+    bits = jax.random.bernoulli(KEY, 0.4, (64, 512)).astype(jnp.int32)
+    w_ref = pack_bits_ref(bits).reshape(8, 128)
+    w_pal = fields_pack_pallas(bits, 1, interpret=True)
     assert bool((w_ref == w_pal).all())
-    assert bool((unpack_bits_pallas(w_pal, interpret=True) == bits).all())
-    assert bool((unpack_bits_ref(w_ref) == bits).all())
+    assert bool((fields_unpack_pallas(w_pal, 1, interpret=True)
+                 == bits).all())
+    assert bool((unpack_bits_ref(w_ref) == bits.reshape(8, -1)).all())
     for n in (1, 31, 33, 777, 4096):
         flat = jax.random.bernoulli(jax.random.fold_in(KEY, n), 0.5,
                                     (n,)).astype(jnp.int32)
-        a = ops.pack_words(flat, use_pallas=False)
-        b = ops.pack_words(flat, use_pallas=True)
+        a = ops.pack_fields(flat, 1, use_pallas=False)
+        b = ops.pack_fields(flat, 1, use_pallas=True)
         assert a.shape == (-(-n // 32),) and bool((a == b).all()), n
-        assert bool((ops.unpack_words(a, n, use_pallas=True) == flat).all())
+        assert bool((ops.unpack_fields(a, n, 1) == flat).all())
 
 
 def test_pallas_codec_entire_model():
-    """A use_pallas codec through the 1-unit entire-model schedule (the
+    """The Pallas codec through the 1-unit entire-model schedule (the
     non-vmapped hot path): still bit-identical to sim."""
     t = _tree()
     sm = stacked_mask(t)
     c = make_compressor("qsgd", levels=16)
-    codec = wire_codec(c, use_pallas=True)
+    codec = wire_codec(c)
     plan = build_plan(t, sm, Granularity("entire_model"))
     sched = build_schedule(plan, 0.0)
     ref = plan.execute(lambda x, k: c.sim(x, k), t, KEY)
