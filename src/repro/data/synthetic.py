@@ -23,47 +23,42 @@ import numpy as np
 Array = jax.Array
 
 
-#: largest vocabulary whose dense (vocab, vocab) transition matrix is kept
-#: (64 MiB in f32); a published LM vocabulary (~50k-150k) would need
-#: 10-90 GB, so above it every token gets a fixed set of successors
-DENSE_MARKOV_VOCAB = 4096
+#: successors of each token in the Markov chain: a dense (vocab, vocab)
+#: transition matrix would take 10-90 GB at a published LM vocabulary
+#: (~50k-150k tokens)
 MARKOV_SUCCESSORS = 64
 
 
 def make_markov(vocab: int, seed: int = 0, concentration: float = 0.3):
-    """Low-entropy (learnable) first-order Markov chain over the vocab: a
-    row-stochastic (vocab, vocab) matrix up to DENSE_MARKOV_VOCAB, else
-    (successors (vocab, MARKOV_SUCCESSORS) int32, their log-probabilities
-    f32) — the same Gumbel construction restricted to random successors."""
+    """Low-entropy (learnable) first-order Markov chain over the vocab:
+    each token moves to one of MARKOV_SUCCESSORS random successors with
+    Gumbel-peaked probabilities -> (successors (vocab, MARKOV_SUCCESSORS)
+    int32, their log-probabilities f32)."""
     rng = np.random.default_rng(seed)
-    if vocab > DENSE_MARKOV_VOCAB:
-        succ = rng.integers(0, vocab, size=(vocab, MARKOV_SUCCESSORS))
-        logits = rng.gumbel(size=(vocab, MARKOV_SUCCESSORS)) / concentration
-        logp = logits - logits.max(axis=1, keepdims=True)
-        logp = logp - np.log(np.exp(logp).sum(axis=1, keepdims=True))
-        return (jnp.asarray(succ, jnp.int32), jnp.asarray(logp, jnp.float32))
-    logits = rng.gumbel(size=(vocab, vocab)) / concentration
-    p = np.exp(logits - logits.max(axis=1, keepdims=True))
-    p = p / p.sum(axis=1, keepdims=True)
-    return jnp.asarray(p, jnp.float32)
+    succ = rng.integers(0, vocab, size=(vocab, MARKOV_SUCCESSORS))
+    logits = rng.gumbel(size=(vocab, MARKOV_SUCCESSORS)) / concentration
+    logp = logits - logits.max(axis=1, keepdims=True)
+    logp = logp - np.log(np.exp(logp).sum(axis=1, keepdims=True))
+    return (jnp.asarray(succ, jnp.int32), jnp.asarray(logp, jnp.float32))
+
+
+def _markov_step(trans, tok: Array, key: Array) -> Array:
+    """Next token of every sequence in `tok` (B,) under the chain."""
+    succ, logp = trans
+    j = jax.random.categorical(key, logp[tok])
+    return jnp.take_along_axis(succ[tok], j[:, None], axis=1)[:, 0]
 
 
 @partial(jax.jit, static_argnums=(2, 3))
 def markov_lm_batch(key: Array, trans, batch: int, seq: int):
-    """Sample (tokens, targets) from the Markov chain (either form of
-    make_markov); targets = next token."""
-    sparse = isinstance(trans, tuple)
-    vocab = trans[0].shape[0] if sparse else trans.shape[0]
+    """Sample (tokens, targets) from the Markov chain of make_markov;
+    targets = next token."""
+    vocab = trans[0].shape[0]
     k0, k1 = jax.random.split(key)
     first = jax.random.randint(k0, (batch,), 0, vocab)
 
     def step(tok, k):
-        if sparse:
-            succ, logp = trans
-            j = jax.random.categorical(k, logp[tok])
-            nxt = jnp.take_along_axis(succ[tok], j[:, None], axis=1)[:, 0]
-        else:
-            nxt = jax.random.categorical(k, jnp.log(trans[tok] + 1e-9))
+        nxt = _markov_step(trans, tok, k)
         return nxt, nxt
 
     keys = jax.random.split(k1, seq)
@@ -151,7 +146,7 @@ def noniid_classification_batch(key: Array, proportions: Array,
 
 
 @partial(jax.jit, static_argnums=(3, 4))
-def noniid_markov_lm_batch(key: Array, trans: Array, proportions: Array,
+def noniid_markov_lm_batch(key: Array, trans, proportions: Array,
                            per_worker: int, seq: int):
     """Skewed per-worker LM batches: worker w's sequences START from
     Categorical(proportions[w]) over the vocab instead of uniform, then
@@ -167,7 +162,7 @@ def noniid_markov_lm_batch(key: Array, trans: Array, proportions: Array,
                                        shape=(per_worker,))
 
         def step(tok, k):
-            nxt = jax.random.categorical(k, jnp.log(trans[tok] + 1e-9))
+            nxt = _markov_step(trans, tok, k)
             return nxt, nxt
 
         keys = jax.random.split(k1, seq)

@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the compression hot path (+ fused RMSNorm).
 
-How a kernel runs follows the platform (`ops.interpret_mode`): Mosaic
-compiles it on TPU; on CPU its body runs as ordinary jax ops.
+How a kernel runs follows `jax.default_backend()` (`ops.interpret_mode`):
+Mosaic compiles it on TPU; on CPU its body runs as ordinary jax ops.
 
 The wire hot path is the fused single-launch compress+pack family in
 `ops` — one kernel launch per UnitPlan bucket, payloads byte-identical

@@ -3,9 +3,9 @@
 Handle padding/reshaping from arbitrary flat gradients to the kernels'
 (rows, 512) tiled layout, generate the stochastic-rounding uniforms, and
 run the pure-jnp reference instead when a caller passes use_pallas=False
-(the tests compare the two). How a kernel runs follows the platform the
-computation is traced for (`interpret_mode`): compiled by Mosaic on TPU,
-interpreted on CPU, refused anywhere else.
+(the tests compare the two). How a kernel runs follows
+`jax.default_backend()` when the op is traced (`interpret_mode`):
+compiled by Mosaic on TPU, interpreted on CPU, refused anywhere else.
 """
 from __future__ import annotations
 

@@ -62,12 +62,12 @@ def word_shape(R: int, width: int) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct((R // _WR, PACK_C // 4 * width), jnp.uint32)
 
 
-def _fields_pack_kernel(f_ref, o_ref, *, width: int, tiled: bool):
-    o_ref[...] = ref.pack_fields_tile(f_ref[...], width, tiled=tiled)
+def _fields_pack_kernel(f_ref, o_ref, *, width: int):
+    o_ref[...] = ref.pack_fields_tile(f_ref[...], width)
 
 
-def _fields_unpack_kernel(w_ref, o_ref, *, width: int, tiled: bool):
-    o_ref[...] = ref.unpack_fields_tile(w_ref[...], width, tiled=tiled)
+def _fields_unpack_kernel(w_ref, o_ref, *, width: int):
+    o_ref[...] = ref.unpack_fields_tile(w_ref[...], width)
 
 
 def fields_pack_pallas(fields: jax.Array, width: int, *,
@@ -77,8 +77,7 @@ def fields_pack_pallas(fields: jax.Array, width: int, *,
     R, C = fields.shape
     assert C == PACK_C, (R, C)
     return pl.pallas_call(
-        functools.partial(_fields_pack_kernel, width=width,
-                          tiled=not interpret),
+        functools.partial(_fields_pack_kernel, width=width),
         grid=pack_grid(R),
         in_specs=[tile_spec(R)],
         out_specs=word_spec(R, width),
@@ -94,8 +93,7 @@ def fields_unpack_pallas(words: jax.Array, width: int, *,
     R = words.shape[0] * _WR
     assert words.shape == word_shape(R, width).shape, (words.shape, width)
     return pl.pallas_call(
-        functools.partial(_fields_unpack_kernel, width=width,
-                          tiled=not interpret),
+        functools.partial(_fields_unpack_kernel, width=width),
         grid=pack_grid(R),
         in_specs=[word_spec(R, width)],
         out_specs=tile_spec(R),

@@ -105,18 +105,17 @@ def _row_positions(block_shape, rpu: int):
 
 
 def _qsgd_pack_kernel(x_ref, k0_ref, k1_ref, nrm_ref, o_ref, *,
-                      levels: int, width: int, d: int, rpu: int, tiled: bool):
+                      levels: int, width: int, d: int, rpu: int):
     x = x_ref[...]                                   # (R, 512) f32
     pos = _row_positions(x.shape, rpu)
     u = prng.uniform_at(k0_ref[...], k1_ref[...], pos)
     codes = ref.qsgd_codes_ref(x, u, nrm_ref[...], levels)
     codes = jnp.where(pos < d, codes, 0)             # zero word padding
-    o_ref[...] = ref.pack_fields_tile(codes, width, tiled=tiled)
+    o_ref[...] = ref.pack_fields_tile(codes, width)
 
 
-def _qsgd_unpack_kernel(w_ref, fac_ref, o_ref, *, levels: int, width: int,
-                        tiled: bool):
-    codes = ref.unpack_fields_tile(w_ref[...], width, tiled=tiled)
+def _qsgd_unpack_kernel(w_ref, fac_ref, o_ref, *, levels: int, width: int):
+    codes = ref.unpack_fields_tile(w_ref[...], width)
     o_ref[...] = ref.qsgd_decode_ref(codes, fac_ref[...], levels)
 
 
@@ -134,7 +133,7 @@ def qsgd_pack_pallas_rows(x: jax.Array, k0: jax.Array, k1: jax.Array,
     col = tile_spec(R, 1)
     return pl.pallas_call(
         functools.partial(_qsgd_pack_kernel, levels=levels, width=width,
-                          d=d, rpu=rpu, tiled=not interpret),
+                          d=d, rpu=rpu),
         grid=pack_grid(R),
         in_specs=[tile_spec(R), col, col, col],
         out_specs=word_spec(R, width),
@@ -151,8 +150,7 @@ def qsgd_unpack_pallas_rows(words: jax.Array, facs: jax.Array, levels: int,
     R = facs.shape[0]
     assert words.shape == word_shape(R, width).shape, (words.shape, width)
     return pl.pallas_call(
-        functools.partial(_qsgd_unpack_kernel, levels=levels, width=width,
-                          tiled=not interpret),
+        functools.partial(_qsgd_unpack_kernel, levels=levels, width=width),
         grid=pack_grid(R),
         in_specs=[word_spec(R, width), tile_spec(R, 1)],
         out_specs=tile_spec(R),

@@ -118,13 +118,8 @@ def sign_decode_ref(codes: Array) -> Array:
 # word are brought onto the SUBLANE axis by 128x128 transposes (each
 # 128-lane slice of a tile row is 4 chunks of 32 fields); the shifts and
 # ORs then combine whole rows, and one transpose puts the words back on
-# lanes. Shared by the Pallas kernel bodies and the jnp twins (pure
-# jnp => identical arithmetic and identical payloads); only the transpose
-# differs: Mosaic lowers one 2-D transpose per 128x128 tile (`tiled`,
-# what the compiled kernels run), while XLA — the jnp twins and the
-# interpreted kernel bodies on CPU — takes the whole tile in one 4-D
-# transpose (far fewer ops to compile). tests/test_fused_kernels.py
-# holds the two equal.
+# lanes. Shared by the Pallas kernel bodies (compiled or interpreted) and
+# the jnp twins: pure jnp => identical arithmetic and identical payloads.
 # --------------------------------------------------------------------------
 
 #: tile rows whose fields fill one row of packed words
@@ -142,8 +137,7 @@ def _word_pieces(width: int):
     return spans
 
 
-def pack_fields_tile(fields: Array, width: int, tiled: bool = False
-                     ) -> Array:
+def pack_fields_tile(fields: Array, width: int) -> Array:
     """(R, 512) int32 fields with R % 8 == 0, values < 2**width ->
     (R//8, 128*width) uint32 words. Read row-major, the words are the
     little-endian bit stream of the fields read row-major: field i's low
@@ -152,13 +146,9 @@ def pack_fields_tile(fields: Array, width: int, tiled: bool = False
     a = R // ROWS_PER_WORD_ROW
     v = fields.astype(jnp.uint32).reshape(a, ROWS_PER_WORD_ROW, C)
     # (sub-tile, chunk) on axis 0, field-in-chunk j on axis 1, a on lanes
-    if tiled:
-        subs = [v[:, k, m:m + 128].T
-                for k in range(ROWS_PER_WORD_ROW) for m in range(0, C, 128)]
-        t = jnp.stack(subs).reshape(-1, 32, a)
-    else:
-        t = v.reshape(a, ROWS_PER_WORD_ROW, C // 128, 128).transpose(
-            1, 2, 3, 0).reshape(-1, 32, a)
+    subs = [v[:, k, m:m + 128].T
+            for k in range(ROWS_PER_WORD_ROW) for m in range(0, C, 128)]
+    t = jnp.stack(subs).reshape(-1, 32, a)
     words = []
     for pieces in _word_pieces(width):
         w = None
@@ -171,8 +161,7 @@ def pack_fields_tile(fields: Array, width: int, tiled: bool = False
     return jnp.stack(words, axis=1).reshape(-1, a).T
 
 
-def unpack_fields_tile(words: Array, width: int, tiled: bool = False
-                       ) -> Array:
+def unpack_fields_tile(words: Array, width: int) -> Array:
     """(A, 128*width) uint32 words -> (8*A, 512) int32 fields. Inverse of
     pack_fields_tile."""
     a, W = words.shape
@@ -189,10 +178,6 @@ def unpack_fields_tile(words: Array, width: int, tiled: bool = False
         fields.append(f & mask)
     f = jnp.stack(fields, axis=1).reshape(n_chunks // 4, 128, a)
     per_k = n_chunks // 4 // ROWS_PER_WORD_ROW       # 128-lane groups per row
-    if not tiled:
-        return f.reshape(ROWS_PER_WORD_ROW, per_k, 128, a).transpose(
-            3, 0, 1, 2).reshape(ROWS_PER_WORD_ROW * a, per_k * 128).astype(
-                jnp.int32)
     subs = [f[i].T for i in range(n_chunks // 4)]    # (a, 128) each
     rows = [jnp.concatenate(subs[k * per_k:(k + 1) * per_k], axis=1)
             for k in range(ROWS_PER_WORD_ROW)]

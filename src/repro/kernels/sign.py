@@ -29,16 +29,16 @@ BLOCK_C = 512
 MAJ_C = 512            # majority-vote word columns per grid step
 
 
-def _sign_pack_kernel(x_ref, o_ref, *, d: int, rpu: int, tiled: bool):
+def _sign_pack_kernel(x_ref, o_ref, *, d: int, rpu: int):
     from repro.kernels.qsgd import _row_positions
     x = x_ref[...]                                   # (R, 512) f32
     pos = _row_positions(x.shape, rpu)
     codes = jnp.where(pos < d, ref.sign_codes_ref(x), 0)
-    o_ref[...] = ref.pack_fields_tile(codes, 1, tiled=tiled)
+    o_ref[...] = ref.pack_fields_tile(codes, 1)
 
 
-def _sign_unpack_kernel(w_ref, o_ref, *, tiled: bool):
-    codes = ref.unpack_fields_tile(w_ref[...], 1, tiled=tiled)
+def _sign_unpack_kernel(w_ref, o_ref):
+    codes = ref.unpack_fields_tile(w_ref[...], 1)
     o_ref[...] = ref.sign_decode_ref(codes)
 
 
@@ -54,8 +54,7 @@ def sign_pack_pallas_rows(x: jax.Array, *, d: int, rpu: int,
     R, C = x.shape
     assert C == BLOCK_C, (R, C)
     return pl.pallas_call(
-        functools.partial(_sign_pack_kernel, d=d, rpu=rpu,
-                          tiled=not interpret),
+        functools.partial(_sign_pack_kernel, d=d, rpu=rpu),
         grid=pack_grid(R),
         in_specs=[tile_spec(R)],
         out_specs=word_spec(R, 1),
@@ -71,7 +70,7 @@ def sign_unpack_pallas_rows(words: jax.Array, *,
     R = words.shape[0] * 8
     assert words.shape == word_shape(R, 1).shape, words.shape
     return pl.pallas_call(
-        functools.partial(_sign_unpack_kernel, tiled=not interpret),
+        _sign_unpack_kernel,
         grid=pack_grid(R),
         in_specs=[word_spec(R, 1)],
         out_specs=tile_spec(R),

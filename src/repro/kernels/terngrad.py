@@ -74,18 +74,18 @@ TERN_WIDTH = 2
 
 
 def _tern_pack_kernel(x_ref, k0_ref, k1_ref, s_ref, o_ref, *,
-                      d: int, rpu: int, tiled: bool):
+                      d: int, rpu: int):
     from repro.kernels.qsgd import _row_positions
     x = x_ref[...]                                   # (R, 512) f32
     pos = _row_positions(x.shape, rpu)
     u = prng.uniform_at(k0_ref[...], k1_ref[...], pos)
     codes = ref.terngrad_codes_ref(x, u, s_ref[...])
     codes = jnp.where(pos < d, codes, 0)             # zero word padding
-    o_ref[...] = ref.pack_fields_tile(codes, TERN_WIDTH, tiled=tiled)
+    o_ref[...] = ref.pack_fields_tile(codes, TERN_WIDTH)
 
 
-def _tern_unpack_kernel(w_ref, s_ref, o_ref, *, tiled: bool):
-    codes = ref.unpack_fields_tile(w_ref[...], TERN_WIDTH, tiled=tiled)
+def _tern_unpack_kernel(w_ref, s_ref, o_ref):
+    codes = ref.unpack_fields_tile(w_ref[...], TERN_WIDTH)
     o_ref[...] = ref.terngrad_decode_ref(codes, s_ref[...])
 
 
@@ -101,8 +101,7 @@ def terngrad_pack_pallas_rows(x: jax.Array, k0: jax.Array, k1: jax.Array,
     assert k0.shape == k1.shape == scales.shape == (R, 1)
     col = tile_spec(R, 1)
     return pl.pallas_call(
-        functools.partial(_tern_pack_kernel, d=d, rpu=rpu,
-                          tiled=not interpret),
+        functools.partial(_tern_pack_kernel, d=d, rpu=rpu),
         grid=pack_grid(R),
         in_specs=[tile_spec(R), col, col, col],
         out_specs=word_spec(R, TERN_WIDTH),
@@ -118,7 +117,7 @@ def terngrad_unpack_pallas_rows(words: jax.Array, scales: jax.Array, *,
     R = scales.shape[0]
     assert words.shape == word_shape(R, TERN_WIDTH).shape, words.shape
     return pl.pallas_call(
-        functools.partial(_tern_unpack_kernel, tiled=not interpret),
+        _tern_unpack_kernel,
         grid=pack_grid(R),
         in_specs=[word_spec(R, TERN_WIDTH), tile_spec(R, 1)],
         out_specs=tile_spec(R),

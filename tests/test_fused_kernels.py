@@ -95,16 +95,16 @@ def test_pack_fields_matches_bitexpand(width, k):
 
 
 @pytest.mark.parametrize("width", [1, 2, 6, 9, 19])
-def test_tiled_transposes_match_vectorized(width):
-    """The per-128x128-tile transposes the compiled kernels run move the
-    same fields as the single transpose of the jnp twins and of the
-    interpreted kernels, both ways."""
+def test_pack_fields_tile_matches_bitexpand(width):
+    """A whole (64, 512) tile through the per-128-lane transposes every
+    kernel body runs: its words, read row-major, are the bit-expansion
+    oracle's stream of the fields read row-major, and unpacking gives
+    the fields back."""
     f = jax.random.randint(jax.random.fold_in(KEY, width), (64, 512), 0,
                            1 << width, dtype=jnp.int32)
     words = ref.pack_fields_tile(f, width)
-    _assert_bitwise(ref.pack_fields_tile(f, width, tiled=True), words, width)
-    _assert_bitwise(ref.unpack_fields_tile(words, width, tiled=True),
-                    ref.unpack_fields_tile(words, width), width)
+    _assert_bitwise(words.reshape(-1),
+                    ref.pack_fields_bitexpand_ref(f.reshape(-1), width), width)
     _assert_bitwise(ref.unpack_fields_tile(words, width), f, width)
 
 
@@ -138,7 +138,7 @@ _FUSED_OPS = ("qsgd_pack_units", "qsgd_unpack_units", "qsgd_unpack_ef_units",
 @contextlib.contextmanager
 def _ops_path(use_pallas):
     """Run the fused codec's ops on the Pallas kernels or on their jnp
-    twins; the legacy per-unit reference always packs with the kernels."""
+    twins."""
     saved = {name: getattr(ops, name) for name in _FUSED_OPS}
     try:
         if not use_pallas:
@@ -150,15 +150,36 @@ def _ops_path(use_pallas):
             setattr(ops, name, fn)
 
 
+def _unpack_fields_oracle(words, k, width):
+    """Inverse of ref.pack_fields_bitexpand_ref through the bit tensor."""
+    bits = ref.unpack_bits_ref(words[None])[0][:k * width].reshape(k, width)
+    return (bits << jnp.arange(width, dtype=jnp.int32)).sum(axis=1)
+
+
+@contextlib.contextmanager
+def _oracle_packer():
+    """The legacy per-unit wire path packs and unpacks its field legs
+    through the bit-expansion oracle, so the reference shares no packing
+    code with the fused kernels and their jnp twins."""
+    saved = ops.pack_fields, ops.unpack_fields
+    try:
+        ops.pack_fields = ref.pack_fields_bitexpand_ref
+        ops.unpack_fields = _unpack_fields_oracle
+        yield
+    finally:
+        ops.pack_fields, ops.unpack_fields = saved
+
+
 def _codec_roundtrip_identity(name, kw, d, n, use_pallas):
     comp = make_compressor(name, **kw)
     fused = wire_codec(comp, fused=True)
     legacy = wire_codec(comp, fused=False)
     x, keys = _bucket(d, n)
-    pay_l = legacy.encode_batch(x, keys)
-    xhat_l = legacy.decode_batch(pay_l, d)
     e = x * 1.5
-    eh_l, m_l = legacy.decode_ef_batch(pay_l, e, d)
+    with _oracle_packer():
+        pay_l = legacy.encode_batch(x, keys)
+        xhat_l = legacy.decode_batch(pay_l, d)
+        eh_l, m_l = legacy.decode_ef_batch(pay_l, e, d)
     with _ops_path(use_pallas):
         pay_f = fused.encode_batch(x, keys)
         xhat_f = fused.decode_batch(pay_f, d)

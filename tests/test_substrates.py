@@ -82,9 +82,14 @@ def test_lm_batches_deterministic_and_learnable():
     assert jnp.array_equal(a["tokens"], b["tokens"])
     assert a["tokens"].shape == (4, 16)
     # targets are the next-token shift of the same chain
-    trans = make_markov(64, 5)
-    batch = markov_lm_batch(jax.random.key(1), trans, 8, 32)
-    probs = trans[batch["tokens"].reshape(-1), batch["targets"].reshape(-1)]
+    succ, logp = make_markov(64, 5)
+    batch = markov_lm_batch(jax.random.key(1), (succ, logp), 8, 32)
+    tok = batch["tokens"].reshape(-1)
+    tgt = batch["targets"].reshape(-1)
+    # probability of each sampled transition (a successor may repeat)
+    probs = jnp.sum(jnp.where(succ[tok] == tgt[:, None],
+                              jnp.exp(logp[tok]), 0.0), axis=1)
+    assert bool((probs > 0).all())       # every target is a successor
     # sampled transitions concentrate on high-probability entries
     assert float(jnp.mean(probs)) > 1.0 / 64 * 2
 
