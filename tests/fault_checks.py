@@ -19,14 +19,17 @@ distinct workers:
   5. threaded error feedback under per-hop bit flips with resend stays
      bitwise on the clean trajectory across steps.
 """
+import functools
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 
 import jax                     # noqa: E402
 import jax.numpy as jnp        # noqa: E402
-from jax.sharding import PartitionSpec as P  # noqa: E402
+from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
+from _sweep_helpers import (assert_trees_bitwise, per_worker,  # noqa: E402
+                            ring_tree)
 from repro.core import (CompressionConfig, Granularity,  # noqa: E402
                         compressed_allreduce, make_compressor,
                         stacked_mask)
@@ -39,27 +42,6 @@ KEY = jax.random.key(7)
 N = jax.local_device_count()
 assert N == 4, f"expected 4 virtual devices, got {N}"
 MESH = make_host_mesh(N, 1)
-
-
-def _tree():
-    ks = [jax.random.fold_in(jax.random.key(3), i) for i in range(4)]
-    return {"dense": jax.random.normal(ks[0], (8, 16)),
-            "blocks": jax.random.normal(ks[1], (3, 4, 10)),
-            "odd": jax.random.normal(ks[2], (7,)),
-            "scalar": jax.random.normal(ks[3], ())}
-
-
-def _per_worker(g):
-    i = jax.lax.axis_index("data").astype(jnp.float32)
-    return jax.tree_util.tree_map(lambda x: x * (1.0 + i), g)
-
-
-def _bitwise(a, b, ctx):
-    for x, y in zip(jax.tree_util.tree_leaves(a),
-                    jax.tree_util.tree_leaves(b)):
-        assert x.shape == y.shape and x.dtype == y.dtype, ctx
-        assert bool((x == y).all()), (
-            ctx, float(jnp.max(jnp.abs(x - y))))
 
 
 def _differs(a, b):
@@ -79,7 +61,7 @@ def _run(strat, spec, *, resend=True, chunk=None, ef_steps=0):
     """One shard_map'd compressed_allreduce under an injector. Returns
     (out, total_detections, messages_per_device) — or (outs, ef, det)
     with `ef_steps` threaded error feedback."""
-    t = _tree()
+    t = ring_tree()
     sm = stacked_mask(t)
     cfg = _cfg(strat)
     if ef_steps:
@@ -89,7 +71,7 @@ def _run(strat, spec, *, resend=True, chunk=None, ef_steps=0):
     inj = None if spec is None else FaultInjector(spec, resend=resend)
 
     def f(g, ef, key):
-        g = _per_worker(g)
+        g = per_worker(g)
         if cfg.error_feedback:
             out, ef = compressed_allreduce(g, sm, cfg, ("data",), key, N,
                                            wire=True, ef_state=ef,
@@ -116,7 +98,10 @@ def _run(strat, spec, *, resend=True, chunk=None, ef_steps=0):
     if not ef_steps:
         out, _ef, det, msgs = fn(t, t, KEY)   # ef arg unused
         return out, int(det), int(msgs)
-    ef = jax.tree_util.tree_map(jnp.zeros_like, t)
+    # the residual enters with the sharding it leaves with, so the step
+    # compiles once rather than again at step 1
+    ef = jax.device_put(jax.tree_util.tree_map(jnp.zeros_like, t),
+                        NamedSharding(MESH, P()))
     outs, det_total = [], 0
     for i in range(ef_steps):
         out, ef, det, _ = fn(t, ef, jax.random.fold_in(KEY, i))
@@ -125,13 +110,19 @@ def _run(strat, spec, *, resend=True, chunk=None, ef_steps=0):
     return outs, ef, det_total
 
 
+@functools.lru_cache(maxsize=None)
+def _clean_ring():
+    """The uninjected ring aggregate every ring check compares to."""
+    return _run("ring", None)[0]
+
+
 def check_ring_bitflip_resend():
-    clean, _, _ = _run("ring", None)
+    clean = _clean_ring()
     for chunk in (None, 64.0):
         out, det, msgs = _run("ring", CorruptionSpec(prob=1.0, seed=5),
                               resend=True, chunk=chunk)
         assert msgs > 0 and det == N * msgs, (det, msgs, chunk)
-        _bitwise(out, clean, ("ring-bitflip-resend", chunk))
+        assert_trees_bitwise(out, clean, ("ring-bitflip-resend", chunk))
         # without resend the corrupted hops poison the aggregate
         bad, det2, _ = _run("ring", CorruptionSpec(prob=1.0, seed=5),
                             resend=False, chunk=chunk)
@@ -140,17 +131,17 @@ def check_ring_bitflip_resend():
 
 
 def check_ring_drop_hop():
-    clean, _, _ = _run("ring", None)
+    clean = _clean_ring()
     out, det, msgs = _run("ring",
                           CorruptionSpec(prob=1.0, mode="drop_hop",
                                          seed=6), resend=True)
     assert msgs > 0 and det == N * msgs, (det, msgs)
-    _bitwise(out, clean, "ring-drop-resend")
+    assert_trees_bitwise(out, clean, "ring-drop-resend")
     print("ring dropped hops: init=1 catches zeros, resend == clean: OK")
 
 
 def check_ring_dup_hop_limitation():
-    clean, _, _ = _run("ring", None)
+    clean = _clean_ring()
     out, det, msgs = _run("ring",
                           CorruptionSpec(prob=1.0, mode="dup_hop",
                                          seed=8), resend=True)
@@ -165,15 +156,15 @@ def check_allgather_bitflip_resend():
     out, det, msgs = _run("allgather", CorruptionSpec(prob=1.0, seed=9),
                           resend=True)
     assert msgs > 0 and det == N * msgs, (det, msgs)
-    _bitwise(out, clean, "allgather-bitflip-resend")
+    assert_trees_bitwise(out, clean, "allgather-bitflip-resend")
     print("allgather wire bit flips: all detected, resend == clean: OK")
 
 
 def check_prob0_passthrough():
-    clean, _, _ = _run("ring", None)
+    clean = _clean_ring()
     out, det, msgs = _run("ring", CorruptionSpec(prob=0.0))
     assert det == 0 and msgs == 0
-    _bitwise(out, clean, "prob0-passthrough")
+    assert_trees_bitwise(out, clean, "prob0-passthrough")
     print("prob=0 injector: byte-identical pass-through: OK")
 
 
@@ -183,8 +174,8 @@ def check_ring_ef_resend():
                          resend=True, ef_steps=3)
     assert det > 0
     for i, (r, g) in enumerate(zip(clean_outs, outs)):
-        _bitwise(r, g, ("ring-ef-resend", i))
-    _bitwise(ef, clean_ef, "ring-ef-resend-state")
+        assert_trees_bitwise(r, g, ("ring-ef-resend", i))
+    assert_trees_bitwise(ef, clean_ef, "ring-ef-resend-state")
     print("ring 3-step EF under bit flips with resend == clean: OK")
 
 

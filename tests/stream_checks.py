@@ -33,6 +33,8 @@ import jax                     # noqa: E402
 import jax.numpy as jnp        # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
+from _sweep_helpers import (assert_trees_bitwise, per_worker,  # noqa: E402
+                            ring_tree)
 from repro.core import (CompressionConfig, FUSE_ALL, Granularity,  # noqa: E402
                         build_plan, build_schedule, compressed_allreduce,
                         make_compressor, stacked_mask)
@@ -46,37 +48,15 @@ assert N == 4, f"expected 4 virtual devices, got {N}"
 MESH = make_host_mesh(N, 1)
 
 
-def _tree():
-    ks = [jax.random.fold_in(jax.random.key(3), i) for i in range(4)]
-    return {"dense": jax.random.normal(ks[0], (8, 16)),
-            "blocks": jax.random.normal(ks[1], (3, 4, 10)),
-            "odd": jax.random.normal(ks[2], (7,)),       # non-divisible
-            "scalar": jax.random.normal(ks[3], ())}
-
-
-def _per_worker(g):
-    """Distinct gradients per ring position."""
-    i = jax.lax.axis_index("data").astype(jnp.float32)
-    return jax.tree_util.tree_map(lambda x: x * (1.0 + i), g)
-
-
-def _bitwise(a, b, ctx):
-    for x, y in zip(jax.tree_util.tree_leaves(a),
-                    jax.tree_util.tree_leaves(b)):
-        assert x.shape == y.shape and x.dtype == y.dtype, ctx
-        assert bool((x == y).all()), (
-            ctx, float(jnp.max(jnp.abs(x - y))))
-
-
 def _run(strat, qw, fb, *, ef_steps=0, chunk=None):
-    t = _tree()
+    t = ring_tree()
     sm = stacked_mask(t)
     cfg = CompressionConfig(qw=qw, granularity=Granularity("layerwise"),
                             strategy=strat, error_feedback=ef_steps > 0,
                             fusion_bytes=fb)
 
     def f(g, ef, key):
-        g = _per_worker(g)
+        g = per_worker(g)
         if ef_steps:
             return compressed_allreduce(g, sm, cfg, ("data",), key, N,
                                         wire=True, ef_state=ef,
@@ -105,7 +85,7 @@ def check_ring_bitwise():
             ref = _run("allgather", qw, fb)
             for chunk in (None, 64.0):
                 got = _run("ring", qw, fb, chunk=chunk)
-                _bitwise(ref, got, ("ring", name, fb, chunk))
+                assert_trees_bitwise(ref, got, ("ring", name, fb, chunk))
     print("ring == allgather bitwise at n=4: OK")
 
 
@@ -115,8 +95,8 @@ def check_ring_ef_bitwise():
         ref_outs, ref_ef = _run("allgather", qw, fb, ef_steps=5)
         got_outs, got_ef = _run("ring", qw, fb, ef_steps=5, chunk=64.0)
         for i, (r, g) in enumerate(zip(ref_outs, got_outs)):
-            _bitwise(r, g, ("ring-ef", fb, "step", i))
-        _bitwise(ref_ef, got_ef, ("ring-ef-state", fb))
+            assert_trees_bitwise(r, g, ("ring-ef", fb, "step", i))
+        assert_trees_bitwise(ref_ef, got_ef, ("ring-ef-state", fb))
     print("ring 5-step EF == allgather at n=4: OK")
 
 
@@ -133,7 +113,7 @@ def _prim_seq(jx, out):
 
 
 def check_double_buffer_jaxpr():
-    t = _tree()
+    t = ring_tree()
     sm = stacked_mask(t)
     comp = make_compressor("qsgd", levels=16)
     plan = build_plan(t, sm, Granularity("layerwise"))
@@ -166,7 +146,7 @@ def check_double_buffer_jaxpr():
 
 def check_hop_spans():
     from repro.obs.calibrate import measure_stream
-    t = _tree()
+    t = ring_tree()
     sm = stacked_mask(t)
     comp = make_compressor("qsgd", levels=16)
     for mode in ("ring", "rs"):
@@ -180,13 +160,13 @@ def check_hop_spans():
 
 
 def check_rs_nondivisible():
-    t = _tree()   # 'odd' (7,) and blocks dim 10: both non-divisible by 4
+    t = ring_tree()   # 'odd' (7,) and blocks dim 10: both non-divisible by 4
     sm = stacked_mask(t)
     qw = make_compressor("identity")
     mesh = MESH
 
     def dense_mean(g):
-        g = _per_worker(g)
+        g = per_worker(g)
         return jax.tree_util.tree_map(
             lambda x: jax.lax.psum(x, ("data",)) / 4.0, g)
 
@@ -198,7 +178,7 @@ def check_rs_nondivisible():
                                 strategy=strat)
 
         def f(g):
-            g = _per_worker(g)
+            g = per_worker(g)
             out, _ = compressed_allreduce(g, sm, cfg, ("data",), KEY, N,
                                           wire=wire)
             return out

@@ -31,16 +31,20 @@ def test_smoke_train_step(arch):
     cfg = get_smoke(arch)
     assert cfg.n_layers <= 5 and cfg.d_model <= 512 and cfg.n_experts <= 4
     m = Model(cfg, DistConfig())
-    params = m.init(KEY)
+    params = jax.jit(m.init)(KEY)
     batch = _batch(cfg)
-    loss, grads = jax.value_and_grad(
-        lambda p: m.loss(p, batch, jax.random.key(1)))(params)
+
+    def loss_fn(p):
+        return m.loss(p, batch, jax.random.key(1))
+
+    # jitted: one program per step instead of one per operation
+    loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params)
     assert loss.shape == () and jnp.isfinite(loss)
     for leaf in jax.tree_util.tree_leaves(grads):
         assert not bool(jnp.isnan(leaf).any())
     # one SGD step decreases nothing catastrophically
     p2 = jax.tree_util.tree_map(lambda p, g: p - 0.01 * g, params, grads)
-    loss2 = m.loss(p2, batch, jax.random.key(1))
+    loss2 = jax.jit(loss_fn)(p2)
     assert jnp.isfinite(loss2)
 
 
@@ -48,20 +52,22 @@ def test_smoke_train_step(arch):
 def test_smoke_prefill_then_decode(arch):
     cfg = get_smoke(arch)
     m = Model(cfg, DistConfig())
-    params = m.init(KEY)
+    params = jax.jit(m.init)(KEY)
     batch = _batch(cfg)
-    logits, cache = m.prefill(params, batch, jax.random.key(2),
-                              cache_len=S + 4)
+    prefill = jax.jit(m.prefill, static_argnames=("cache_len",))
+    logits, cache = prefill(params, batch, jax.random.key(2),
+                            cache_len=S + 4)
     vocab_padded = ((cfg.vocab + 127) // 128) * 128
     assert logits.shape == (B, vocab_padded)
     assert not bool(jnp.isnan(logits).any())
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    lg, cache = m.decode_step(params, tok, jnp.int32(S), cache)
+    decode_step = jax.jit(m.decode_step)
+    lg, cache = decode_step(params, tok, jnp.int32(S), cache)
     assert lg.shape == (B, vocab_padded)
     assert not bool(jnp.isnan(lg).any())
     # a second decode step continues from the updated cache
-    lg2, _ = m.decode_step(params, jnp.argmax(lg, -1).astype(jnp.int32),
-                           jnp.int32(S + 1), cache)
+    lg2, _ = decode_step(params, jnp.argmax(lg, -1).astype(jnp.int32),
+                         jnp.int32(S + 1), cache)
     assert not bool(jnp.isnan(lg2).any())
 
 

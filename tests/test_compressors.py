@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import index_bits, make_compressor, available_compressors
+from repro.core import index_bits, make_compressor
 from repro.core.theory import (check_unbiasedness, empirical_omega,
                                empirical_descent_alignment)
 
@@ -113,8 +113,8 @@ def test_payload_bits_sane():
     assert index_bits(9) == 4
 
 
-@settings(max_examples=25, deadline=None)
-@given(st.integers(min_value=8, max_value=2048),
+@settings(max_examples=15)
+@given(st.sampled_from([8, 31, 32, 33, 100, 511, 512, 513, 1000, 2048]),
        st.sampled_from(["topk", "randomk", "terngrad", "qsgd", "signsgd",
                         "natural"]),
        st.integers(min_value=0, max_value=10_000))
@@ -146,8 +146,8 @@ def test_property_assumption5_holds(d, name, seed):
         assert qn <= norm2 * (1 + 1e-5)  # biased sparsifiers contract
 
 
-@settings(max_examples=20, deadline=None)
-@given(st.integers(min_value=16, max_value=512),
+@settings(max_examples=10)
+@given(st.sampled_from([16, 31, 32, 33, 100, 255, 256, 257, 512]),
        st.integers(min_value=0, max_value=10_000))
 def test_property_descent_alignment_unbiased(d, seed):
     """Assumption 6 / Lemma 2(i): unbiased ops align with the gradient:

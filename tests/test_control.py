@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from _sweep_helpers import smoke_batch, smoke_engine
 from repro.control import (BitBudgetPolicy, CompressionDecision, Controller,
                            GranularitySwitchPolicy, PerDimRatio,
                            StaticPolicy, VarianceBudgetPolicy, accumulate,
@@ -258,7 +259,7 @@ def test_variance_budget_monotone_deterministic():
         prev = bits
 
 
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 @given(st.floats(min_value=1e-4, max_value=1.0),
        st.floats(min_value=1e-4, max_value=1.0))
 def test_property_variance_budget_monotone(b1, b2):
@@ -445,17 +446,8 @@ def test_fusion_policy_picks_threshold_from_model():
 
 @pytest.fixture(scope="module")
 def engine_setup():
-    from repro.configs.registry import get_smoke
-    from repro.launch.engine import Engine
-    from repro.launch.mesh import make_host_mesh
-    cfg = get_smoke("mamba2-1.3b")
-    mesh = make_host_mesh(1, 1)
-    comp = CompressionConfig(qw=make_compressor("qsgd", levels=16),
-                             granularity=Granularity("layerwise"))
-    eng = Engine(cfg, mesh, comp=comp)
-    batch = {"tokens": jnp.ones((4, 16), jnp.int32) * 3,
-             "targets": jnp.ones((4, 16), jnp.int32) * 5}
-    return eng, comp, batch
+    eng = smoke_engine()
+    return eng, eng.comp, smoke_batch()
 
 
 def _run_steps(step_fn, eng, batch, n=2, telem=None):

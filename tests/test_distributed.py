@@ -1,22 +1,11 @@
 """Runs the distributed correctness suite in a subprocess with 8 virtual
 devices (XLA device count must be set before jax initializes)."""
-import os
-import subprocess
-import sys
-
 import pytest
 
 pytestmark = pytest.mark.slow
 
 
-@pytest.mark.timeout(1200)
-def test_distributed_checks():
-    script = os.path.join(os.path.dirname(__file__), "dist_checks.py")
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    res = subprocess.run([sys.executable, script], capture_output=True,
-                         text=True, env=env, timeout=1200)
-    sys.stdout.write(res.stdout[-4000:])
-    sys.stderr.write(res.stderr[-4000:])
-    assert res.returncode == 0, "distributed checks failed"
-    assert "ALL DISTRIBUTED CHECKS PASSED" in res.stdout
+@pytest.mark.timeout(430)
+def test_distributed_checks(run_check_script):
+    run_check_script("dist_checks.py", "ALL DISTRIBUTED CHECKS PASSED",
+                     timeout=400)

@@ -65,9 +65,9 @@ def _naive_ssd(xh, dt, A, Bm, Cm, D):
     return ys.transpose(1, 0, 2, 3) + D[None, None, :, None] * xh, hT
 
 
-@settings(max_examples=10, deadline=None)
+@settings(max_examples=6)
 @given(st.integers(min_value=1, max_value=3),
-       st.integers(min_value=4, max_value=40),
+       st.sampled_from([4, 7, 8, 9, 16, 17, 30, 40]),
        st.sampled_from([2, 4, 8]),
        st.integers(min_value=0, max_value=1000))
 def test_property_ssd_equals_recurrence(B, S, chunk, seed):

@@ -5,8 +5,8 @@ import jax.numpy as jnp
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (Granularity, Identity, apply_unitwise,
-                        make_compressor, stacked_mask, unit_dims)
+from repro.core import (Granularity, apply_unitwise, make_compressor,
+                        stacked_mask, unit_dims)
 
 KEY = jax.random.key(0)
 
@@ -77,7 +77,7 @@ def test_layerwise_statistics_are_per_layer():
         assert jnp.allclose(nz1e, 10.0)
 
 
-@settings(max_examples=15, deadline=None)
+@settings(max_examples=15)
 @given(st.integers(min_value=1, max_value=5),
        st.integers(min_value=1, max_value=64),
        st.integers(min_value=8, max_value=200))

@@ -29,34 +29,15 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import (CompressionConfig, Granularity, build_plan,
-                        build_schedule, make_compressor, stacked_mask,
-                        wire_codec)
+from _sweep_helpers import (KEY, assert_trees_bitwise, grad_tree, smoke_batch,
+                            smoke_engine)
+from repro.core import (Granularity, build_plan, build_schedule,
+                        make_compressor, stacked_mask, wire_codec)
 from repro.obs import (METRICS_SCHEMA_VERSION, TRACE_SCHEMA_VERSION,
                        MetricsRegistry, TraceRecorder, calibrate,
                        count_debug_callbacks, fit_alpha_beta,
                        format_step_summary, measure_schedule, read_jsonl,
                        validate_chrome_trace)
-
-KEY = jax.random.key(0)
-
-
-def _tree(key=KEY):
-    """Mixed pytree with several size classes so readiness order is
-    nontrivial (same shape idiom as tests/test_schedule.py)."""
-    ks = [jax.random.fold_in(key, i) for i in range(5)]
-    return {"blocks": {"w": jax.random.normal(ks[0], (3, 16, 8)),
-                       "b": jax.random.normal(ks[1], (3, 8))},
-            "embed": jax.random.normal(ks[2], (20, 4)),
-            "head": jax.random.normal(ks[3], (4, 2)),
-            "scalar_gain": jax.random.normal(ks[4], ())}
-
-
-def _assert_trees_bitwise(a, b, ctx):
-    for la, lb in zip(jax.tree_util.tree_leaves(a),
-                      jax.tree_util.tree_leaves(b)):
-        assert la.shape == lb.shape and la.dtype == lb.dtype, ctx
-        assert bool((la == lb).all()), ctx
 
 
 def _run_recorded(sched, fn, tree, rec, *, wire=None):
@@ -79,7 +60,7 @@ def _run_recorded(sched, fn, tree, rec, *, wire=None):
 def test_message_spans_match_schedule():
     """Per-bucket threshold, simulated path: one message span per
     schedule message, bucket attribution == plan.readiness_order()."""
-    tree, sm = _tree(), stacked_mask(_tree())
+    tree, sm = grad_tree(), stacked_mask(grad_tree())
     comp = make_compressor("qsgd", levels=16)
     plan = build_plan(tree, sm, Granularity("layerwise"))
     sched = build_schedule(plan, 0.0)
@@ -101,7 +82,7 @@ def test_message_spans_match_schedule():
 
 def test_plan_dispatch_spans():
     """Bare UnitPlan execution records one dispatch span per bucket."""
-    tree, sm = _tree(), stacked_mask(_tree())
+    tree, sm = grad_tree(), stacked_mask(grad_tree())
     comp = make_compressor("signsgd")
     plan = build_plan(tree, sm, Granularity("layerwise"))
     rec = TraceRecorder()
@@ -118,7 +99,7 @@ def test_plan_dispatch_spans():
 def test_wire_stage_spans_and_synthesized_messages():
     """Wire path: per-stage spans carry codec attribution and finalize
     synthesizes exactly num_messages umbrella message spans."""
-    tree, sm = _tree(), stacked_mask(_tree())
+    tree, sm = grad_tree(), stacked_mask(grad_tree())
     comp = make_compressor("qsgd", levels=16)
     plan = build_plan(tree, sm, Granularity("layerwise"))
     sched = build_schedule(plan, float(1 << 10))
@@ -144,7 +125,7 @@ def test_wire_stage_spans_and_synthesized_messages():
 
 
 def test_multi_step_and_summary_format():
-    tree, sm = _tree(), stacked_mask(_tree())
+    tree, sm = grad_tree(), stacked_mask(grad_tree())
     comp = make_compressor("randomk", ratio=0.5)
     plan = build_plan(tree, sm, Granularity("layerwise"))
     sched = build_schedule(plan, math.inf)
@@ -165,7 +146,7 @@ def test_multi_step_and_summary_format():
 # ---------------------------------------------------------------------------
 
 def test_chrome_trace_valid_and_exportable(tmp_path):
-    tree, sm = _tree(), stacked_mask(_tree())
+    tree, sm = grad_tree(), stacked_mask(grad_tree())
     comp = make_compressor("qsgd", levels=16)
     plan = build_plan(tree, sm, Granularity("layerwise"))
     sched = build_schedule(plan, 0.0)
@@ -237,7 +218,7 @@ def test_zero_overhead_when_disabled():
     """recorder=None, recorder=disabled, and no recorder at all stage
     IDENTICAL jaxprs with zero debug_callback equations; enabling the
     recorder adds callbacks but never changes numerics."""
-    tree, sm = _tree(), stacked_mask(_tree())
+    tree, sm = grad_tree(), stacked_mask(grad_tree())
     comp = make_compressor("qsgd", levels=16)
     plan = build_plan(tree, sm, Granularity("layerwise"))
     sched = build_schedule(plan, float(1 << 10))
@@ -261,11 +242,11 @@ def test_zero_overhead_when_disabled():
     got = jax.jit(on)(tree, KEY)
     jax.block_until_ready(got)
     rec.finalize_step(0)
-    _assert_trees_bitwise(ref, got, "recorded-vs-bare")
+    assert_trees_bitwise(ref, got, "recorded-vs-bare")
 
 
 def test_zero_overhead_wire_path():
-    tree, sm = _tree(), stacked_mask(_tree())
+    tree, sm = grad_tree(), stacked_mask(grad_tree())
     comp = make_compressor("signsgd")
     plan = build_plan(tree, sm, Granularity("layerwise"))
     sched = build_schedule(plan, 0.0)
@@ -284,8 +265,8 @@ def test_zero_overhead_wire_path():
     got, gotb = on(tree, KEY)
     jax.block_until_ready(got)
     rec.finalize_step(0)
-    _assert_trees_bitwise(ref, got, "wire-recorded-vs-bare")
-    _assert_trees_bitwise(refb, gotb, "wire-buffers")
+    assert_trees_bitwise(ref, got, "wire-recorded-vs-bare")
+    assert_trees_bitwise(refb, gotb, "wire-buffers")
 
 
 # ---------------------------------------------------------------------------
@@ -294,7 +275,7 @@ def test_zero_overhead_wire_path():
 
 def _tiny_controller(metrics=None):
     from repro.control import CompressionDecision, Controller, StaticPolicy
-    tree = _tree()
+    tree = grad_tree()
     sm = stacked_mask(tree)
     mplan = build_plan(tree, sm, Granularity("layerwise"))
     base = CompressionDecision(qw=make_compressor("randomk", ratio=0.5),
@@ -381,7 +362,7 @@ def test_fit_alpha_beta():
 
 
 def test_calibration_smoke():
-    tree, sm = _tree(), stacked_mask(_tree())
+    tree, sm = grad_tree(), stacked_mask(grad_tree())
     comp = make_compressor("qsgd", levels=16)
     meas = measure_schedule(tree, sm, comp, 0.0, reps=1, warmup=1)
     plan = build_plan(tree, sm, Granularity("layerwise"))
@@ -415,18 +396,10 @@ def test_engine_train_step_trace_and_zero_overhead():
     exactly schedule.num_messages message spans per step, static metrics
     gauges match the schedule, and a disabled tracer keeps the step
     bit-identical with zero staged callbacks."""
-    from repro.configs.registry import get_smoke
     from repro.launch.comm_sched import engine_schedule
-    from repro.launch.engine import Engine
-    from repro.launch.mesh import make_host_mesh
-    cfg = get_smoke("mamba2-1.3b")
-    mesh = make_host_mesh(1, 1)
-    comp = CompressionConfig(qw=make_compressor("qsgd", levels=16),
-                             granularity=Granularity("layerwise"))
-    eng = Engine(cfg, mesh, comp=comp)
+    eng = smoke_engine()
     sched = engine_schedule(eng, 0.0)
-    batch = {"tokens": jnp.ones((4, 16), jnp.int32) * 3,
-             "targets": jnp.ones((4, 16), jnp.int32) * 5}
+    batch = smoke_batch()
     rec, reg = TraceRecorder(), MetricsRegistry()
     fn = eng.build_train_step(schedule=sched, tracer=rec, metrics=reg)
     params, opt_state = eng.init_state(0)
@@ -438,7 +411,7 @@ def test_engine_train_step_trace_and_zero_overhead():
         assert len(rec.message_spans(step=i)) == sched.num_messages
     assert reg.gauges["engine/n_messages"] == sched.num_messages
     assert reg.gauges["engine/n_dispatches"] == \
-        eng.comm_plans(comp)[0].num_dispatches
+        eng.comm_plans(eng.comp)[0].num_dispatches
     assert validate_chrome_trace(rec.chrome_trace())
 
     # zero overhead: disabled tracer == no tracer, bit for bit
@@ -449,7 +422,7 @@ def test_engine_train_step_trace_and_zero_overhead():
     p_bare, _, m_bare = fn_bare(p0, o0, batch, jnp.int32(0))
     p1, o1 = eng.init_state(0)
     p_off, _, m_off = fn_off(p1, o1, batch, jnp.int32(0))
-    _assert_trees_bitwise(p_bare, p_off, "engine-disabled-tracer")
+    assert_trees_bitwise(p_bare, p_off, "engine-disabled-tracer")
     assert float(m_bare["loss"]) == float(m_off["loss"])
 
 
@@ -461,7 +434,7 @@ def test_engine_train_step_trace_and_zero_overhead():
 def test_obs_sweep_span_counts(cname, kw, fb):
     """Full sweep: on both execution paths, per-step message-span count
     == schedule.num_messages and recording never changes numerics."""
-    tree, sm = _tree(), stacked_mask(_tree())
+    tree, sm = grad_tree(), stacked_mask(grad_tree())
     comp = make_compressor(cname, **kw)
     plan = build_plan(tree, sm, Granularity("layerwise"))
     sched = build_schedule(plan, fb)
@@ -471,7 +444,7 @@ def test_obs_sweep_span_counts(cname, kw, fb):
     got = _run_recorded(sched, fn, tree, rec)
     assert rec.finalize_step(0)["n_message_spans"] == sched.num_messages
     ref = jax.jit(lambda t, k: sched.execute(fn, t, k))(tree, KEY)
-    _assert_trees_bitwise(ref, got, (cname, fb, "sim"))
+    assert_trees_bitwise(ref, got, (cname, fb, "sim"))
 
     codec = wire_codec(comp)
     recw = TraceRecorder()
@@ -479,4 +452,4 @@ def test_obs_sweep_span_counts(cname, kw, fb):
     assert recw.finalize_step(0)["n_message_spans"] == sched.num_messages
     refw, _ = jax.jit(
         lambda t, k: sched.execute(None, t, k, wire=codec))(tree, KEY)
-    _assert_trees_bitwise(refw, goww, (cname, fb, "wire"))
+    assert_trees_bitwise(refw, goww, (cname, fb, "wire"))

@@ -13,17 +13,16 @@ import jax.numpy as jnp
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from _sweep_helpers import KEY, grad_tree
 from repro.core import (CompressionConfig, Granularity, build_plan,
                         aggregate_simulated_workers, comm_report,
                         make_compressor, stacked_mask, unit_dims)
 from repro.core.granularity import (apply_unitwise, apply_unitwise_reference,
                                     apply_unitwise_with_state,
                                     apply_unitwise_with_state_reference)
-from repro.core.plan import UnitPlan
 from repro.core.theory import noise_bounds_from_plan
 from repro.kernels import ops
 
-KEY = jax.random.key(0)
 
 GRANS = [Granularity("layerwise"), Granularity("entire_model"),
          Granularity("blockwise", 100)]
@@ -41,16 +40,6 @@ OPERATORS = [
 ]
 
 
-def _tree(key=KEY):
-    """Mixed pytree: scan-stacked leaves of two sizes + loose leaves."""
-    ks = [jax.random.fold_in(key, i) for i in range(5)]
-    return {"blocks": {"w": jax.random.normal(ks[0], (3, 16, 8)),
-                       "b": jax.random.normal(ks[1], (3, 8))},
-            "embed": jax.random.normal(ks[2], (20, 4)),
-            "head": jax.random.normal(ks[3], (4, 2)),
-            "scalar_gain": jax.random.normal(ks[4], ())}
-
-
 def _assert_trees_close(a, b, ctx, atol=1e-6):
     for la, lb in zip(jax.tree_util.tree_leaves(a),
                       jax.tree_util.tree_leaves(b)):
@@ -66,7 +55,7 @@ def _assert_trees_close(a, b, ctx, atol=1e-6):
 @pytest.mark.parametrize("gran", GRANS, ids=lambda g: g.kind)
 @pytest.mark.parametrize("name,kw", OPERATORS)
 def test_plan_matches_reference(gran, name, kw):
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     c = make_compressor(name, **kw)
 
@@ -81,7 +70,7 @@ def test_plan_matches_reference(gran, name, kw):
 @pytest.mark.parametrize("gran", GRANS, ids=lambda g: g.kind)
 def test_plan_matches_reference_with_state(gran):
     """Error-feedback threading: outputs AND residual memories match."""
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     m0 = jax.tree_util.tree_map(lambda x: 0.3 * jnp.ones_like(x), t)
     c = make_compressor("topk", ratio=0.1)
@@ -99,7 +88,7 @@ def test_plan_matches_reference_with_state(gran):
 
 def test_plan_matches_reference_raw_key():
     """Old-style uint32 keys take the raw fold path."""
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     c = make_compressor("qsgd", levels=8)
     rk = jax.random.PRNGKey(11)
@@ -111,7 +100,7 @@ def test_plan_matches_reference_raw_key():
 
 def test_plan_under_jit_and_grad():
     """The plan path is traceable and differentiable (psum-free fn)."""
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     g = Granularity("layerwise")
 
@@ -176,7 +165,7 @@ def test_stacked_bucket_is_contiguous():
 
 
 def test_plan_cache_returns_same_object():
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     g = Granularity("layerwise")
     assert build_plan(t, sm, g) is build_plan(t, sm, g)
@@ -192,7 +181,7 @@ def test_plan_cache_returns_same_object():
 
 @pytest.mark.parametrize("gran", GRANS, ids=lambda g: g.kind)
 def test_unit_dims_match_on_synthetic_tree(gran):
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     plan = build_plan(t, sm, gran)
     assert list(plan.unit_dims) == unit_dims(t, sm, gran)
@@ -222,7 +211,7 @@ def test_unit_dims_match_on_config_zoo(arch):
 
 
 def test_comm_report_accepts_plan():
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     g = Granularity("layerwise")
     plan = build_plan(t, sm, g)
@@ -236,7 +225,7 @@ def test_comm_report_accepts_plan():
 def test_noise_bounds_from_plan():
     """Theory reads the plan's unit partition: Trace(A) <= d*max bound
     (the paper's headline), with closed-form QSGD omegas."""
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     plan = build_plan(t, sm, Granularity("layerwise"))
     qw = make_compressor("qsgd", levels=4)
@@ -253,7 +242,7 @@ def test_noise_bounds_from_plan():
 def test_aggregate_simulated_workers_accepts_plan():
     """Passing a prebuilt plan changes nothing numerically."""
     n = 4
-    t = _tree()
+    t = grad_tree()
     wg = jax.tree_util.tree_map(
         lambda x: jnp.stack([x * (i + 1) for i in range(n)]), t)
     sm = stacked_mask(t)
@@ -310,13 +299,17 @@ def test_plan_compress_kernel_path():
 
 
 # ---------------------------------------------------------------------------
-# property test (runs when hypothesis is installed; skips otherwise)
+# property test
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=20, deadline=None)
-@given(st.integers(min_value=1, max_value=6),
-       st.integers(min_value=1, max_value=40),
-       st.integers(min_value=1, max_value=30),
+# Shapes come from fixed lists: each new shape compiles new programs.
+# Stacked rows of 4: one row, either side of the 64-wide blockwise unit
+# (16, 17 rows) and the largest; loose dims: 1, 4 (the size of a
+# one-row layer, so the two share a size class) and the largest.
+@settings(max_examples=8)
+@given(st.sampled_from([1, 2, 6]),
+       st.sampled_from([1, 16, 17, 40]),
+       st.sampled_from([1, 4, 30]),
        st.integers(min_value=0, max_value=10_000))
 def test_property_plan_equivalence(L, rows, loose, seed):
     """Random stacked/loose shapes: plan == legacy for every granularity."""

@@ -1,41 +1,36 @@
 """The resilience plane: wire integrity, fault injection, recovery.
 
-Contracts held here (single-device; the genuinely-multi-device ring-hop
-checks run in the tests/fault_checks.py subprocess via test_resil_
-multidevice_checks):
+Contracts held here (single-device; the detection gate across the codec
+zoo and the genuinely multi-device ring-hop checks are in
+tests/test_resil_detection.py, the resend contract across the zoo in
+tests/test_resil_resend.py):
 
   1. FLETCHER-32: the vectorized in-graph checksum equals the byte-serial
      reference loop (sizes crossing every chunk boundary + a hypothesis
-     property when installed); the init=1 variant gives an all-zero
-     buffer a NONZERO checksum (a dropped/zeroed message never
-     verifies); any single-bit flip is detected (exhaustive small sweep).
+     property); the init=1 variant gives an all-zero buffer a NONZERO
+     checksum (a dropped/zeroed message never verifies); any single-bit
+     flip is detected (exhaustive small sweep).
   2. LAYOUT: codec.integrity reserves exactly one extra uint32 header
      word per fused message; the checksummed span starts past
      [n_buckets, fletcher32]; integrity never changes decoded numerics.
-  3. DETECTION GATE: across the six-codec zoo x granularities on the
-     serialized wire path, every clean message verifies (zero false
-     positives) and every single-bit-flipped message fails verification.
-  4. HARDENED PARSE: parse_message_header accepts exactly the buffers
+  3. HARDENED PARSE: parse_message_header accepts exactly the buffers
      _message_buffer emits and raises ValueError on every mutated
      header (truncation, zero/oversized bucket count, misplaced or
      decreasing or out-of-range offsets, ragged byte length).
-  5. CHECKPOINTS: atomic (no partial file at the final path, tmp never
+  4. CHECKPOINTS: atomic (no partial file at the final path, tmp never
      matches latest_checkpoint), digest-verified (a flipped byte
      raises ValueError), bitwise round-trip.
-  6. RECOVERY: faulted-with-resend training == clean training bitwise;
-     EF residuals are SENDER-side state and stay bitwise clean under
-     receive corruption; a guarded non-finite step skips the update AND
-     conserves the EF residual; repeated corruption flips the dense
-     fallback; partial participation renormalizes the mean over
-     survivors and freezes dead workers' EF rows; train_resilient
-     resume is leaf-for-leaf bitwise (train N == train k, kill, resume).
+  5. RECOVERY: faulted-with-resend training == clean training bitwise;
+     a guarded non-finite step skips the update AND conserves the EF
+     residual; repeated corruption flips the dense fallback; partial
+     participation renormalizes the mean over survivors and freezes
+     dead workers' EF rows; train_resilient resume is leaf-for-leaf
+     bitwise (train N == train k, kill, resume).
 
-The heavy sweeps carry the `fault` marker: tier-1 (`make verify`) only,
+The heavy tests carry the `fault` marker: tier-1 (`make verify`) only,
 excluded from the `make verify-fast` inner loop.
 """
 import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -43,57 +38,17 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from _sweep_helpers import (GRANS, KEY, assert_trees_bitwise, grad_tree,
+                            wire_parts, worker_grads)
 from repro.core import (CompressionConfig, Granularity,
-                        aggregate_simulated_workers, build_plan,
-                        build_schedule, make_compressor, stacked_mask)
+                        aggregate_simulated_workers, make_compressor,
+                        stacked_mask)
 from repro.core.wire import (execute_schedule_wire, fletcher32,
-                             message_layouts, parse_message_header,
-                             verify_message, wire_codec)
+                             parse_message_header, verify_message)
 from repro.ckpt import latest_checkpoint, load_checkpoint, save_checkpoint
 from repro.resil import (FaultInjector, RecoveryConfig, RecoveryManager,
                          train_resilient)
 from repro.sim import CorruptionSpec, Scenario, StragglerSpec, init_ef
-
-KEY = jax.random.key(0)
-
-SIX = [
-    ("topk", {"ratio": 0.25}),
-    ("randomk", {"ratio": 0.3, "scale": True}),
-    ("qsgd", {"levels": 16}),
-    ("terngrad", {}),
-    ("signsgd", {}),
-    ("natural", {}),
-]
-
-GRANS = [Granularity("layerwise"), Granularity("entire_model")]
-
-
-def _tree(key=KEY):
-    ks = [jax.random.fold_in(key, i) for i in range(5)]
-    return {"blocks": {"w": jax.random.normal(ks[0], (3, 16, 8)),
-                       "b": jax.random.normal(ks[1], (3, 8))},
-            "embed": jax.random.normal(ks[2], (20, 4)),
-            "head": jax.random.normal(ks[3], (4, 2)),
-            "scalar_gain": jax.random.normal(ks[4], ())}
-
-
-def _worker_grads(n=4, key=KEY):
-    trees = [_tree(jax.random.fold_in(key, 100 + i)) for i in range(n)]
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
-
-
-def _assert_trees_bitwise(a, b, ctx):
-    for la, lb in zip(jax.tree_util.tree_leaves(a),
-                      jax.tree_util.tree_leaves(b)):
-        assert la.shape == lb.shape and la.dtype == lb.dtype, ctx
-        assert bool((la == lb).all()), (
-            ctx, float(jnp.max(jnp.abs(la - lb))))
-
-
-def _trees_differ(a, b):
-    return any(not bool((x == y).all())
-               for x, y in zip(jax.tree_util.tree_leaves(a),
-                               jax.tree_util.tree_leaves(b)))
 
 
 # ==========================================================================
@@ -131,8 +86,14 @@ def test_fletcher32_matches_reference_chunk_boundaries(size):
     assert got == _fletcher_ref(data.tobytes()), size
 
 
-@given(st.lists(st.integers(0, 255), min_size=0, max_size=512))
-@settings(max_examples=50, deadline=None)
+#: buffer lengths: empty, one byte (odd tail), either side of a 16-bit
+#: word and of 256 bytes, and the largest length
+FLETCHER_SIZES = [0, 1, 2, 3, 255, 256, 257, 511, 512]
+
+
+@given(st.sampled_from(FLETCHER_SIZES).flatmap(
+    lambda n: st.lists(st.integers(0, 255), min_size=n, max_size=n)))
+@settings(max_examples=50)
 def test_fletcher32_property(byte_list):
     data = np.asarray(byte_list, np.uint8)
     assert int(fletcher32(jnp.asarray(data))) == \
@@ -168,19 +129,10 @@ def test_fletcher32_single_bit_flip_always_detected():
 # 2. layout: the integrity header word
 # ==========================================================================
 
-def _wire_parts(name, kw, gran, integrity=True):
-    t = _tree()
-    sm = stacked_mask(t)
-    codec = wire_codec(make_compressor(name, **kw), integrity=integrity)
-    plan = build_plan(t, sm, gran)
-    sched = build_schedule(plan, 0.0)
-    return t, codec, sched, message_layouts(sched, codec)
-
-
 def test_integrity_header_reserves_one_word():
     for gran in GRANS:
-        _, _, sched, lays = _wire_parts("topk", {"ratio": 0.25}, gran)
-        _, _, _, plain = _wire_parts("topk", {"ratio": 0.25}, gran,
+        _, _, sched, lays = wire_parts("topk", {"ratio": 0.25}, gran)
+        _, _, _, plain = wire_parts("topk", {"ratio": 0.25}, gran,
                                      integrity=False)
         for li, lp in zip(lays, plain):
             assert li.checksum and not lp.checksum
@@ -190,7 +142,7 @@ def test_integrity_header_reserves_one_word():
 
 
 def test_verify_message_requires_checksum_layout():
-    _, codec, sched, lays = _wire_parts("topk", {"ratio": 0.25}, GRANS[0],
+    _, codec, sched, lays = wire_parts("topk", {"ratio": 0.25}, GRANS[0],
                                         integrity=False)
     buf = jnp.zeros((lays[0].total_nbytes,), jnp.uint8)
     with pytest.raises(ValueError, match="checksum layout"):
@@ -200,63 +152,23 @@ def test_verify_message_requires_checksum_layout():
 def test_integrity_decode_bit_identical():
     """The checksum word changes the header, never the numerics."""
     for gran in GRANS:
-        t, codec, sched, _ = _wire_parts("qsgd", {"levels": 16}, gran)
-        _, plain_codec, _, _ = _wire_parts("qsgd", {"levels": 16}, gran,
+        t, codec, sched, _ = wire_parts("qsgd", {"levels": 16}, gran)
+        _, plain_codec, _, _ = wire_parts("qsgd", {"levels": 16}, gran,
                                            integrity=False)
         out_i, bufs_i = execute_schedule_wire(sched, codec, None, t, KEY)
         out_p, bufs_p = execute_schedule_wire(sched, plain_codec, None, t,
                                               KEY)
-        _assert_trees_bitwise(out_i, out_p, gran.kind)
+        assert_trees_bitwise(out_i, out_p, gran.kind)
         for bi, bp in zip(bufs_i, bufs_p):
             assert bi.size == bp.size + 4, gran.kind
 
 
 # ==========================================================================
-# 3. the detection gate: six codecs x granularities, serialized path
-# ==========================================================================
-
-def _detection_case(name, kw, gran):
-    t, codec, sched, lays = _wire_parts(name, kw, gran)
-    _, bufs = execute_schedule_wire(sched, codec, None, t, KEY)
-    assert len(bufs) == len(lays) and len(bufs) >= 1
-    rng = np.random.default_rng(11)
-    for buf, lay in zip(bufs, lays):
-        # zero false positives: the clean buffer always verifies
-        assert bool(verify_message(buf, lay)), (name, gran.kind)
-        b = np.asarray(buf)
-        # every sampled single-bit flip in the covered span is caught
-        span = b.size - lay.checksum_span_start
-        for _ in range(8):
-            pos = lay.checksum_span_start + int(rng.integers(span))
-            bit = int(rng.integers(8))
-            c = b.copy()
-            c[pos] ^= np.uint8(1 << bit)
-            assert not bool(verify_message(jnp.asarray(c), lay)), \
-                (name, gran.kind, pos, bit)
-        # a zeroed (dropped) message is caught too
-        z = np.zeros_like(b)
-        z[:lay.checksum_span_start] = b[:lay.checksum_span_start]
-        assert not bool(verify_message(jnp.asarray(z), lay)), \
-            (name, gran.kind)
-
-
-def test_detection_gate_smoke():
-    _detection_case("topk", {"ratio": 0.25}, GRANS[0])
-
-
-@pytest.mark.fault
-@pytest.mark.parametrize("gran", GRANS, ids=lambda g: g.kind)
-@pytest.mark.parametrize("name,kw", SIX, ids=[n for n, _ in SIX])
-def test_detection_gate_zoo(name, kw, gran):
-    _detection_case(name, kw, gran)
-
-
-# ==========================================================================
-# 4. hardened header parse
+# 3. hardened header parse
 # ==========================================================================
 
 def _valid_message():
-    t, codec, sched, lays = _wire_parts("topk", {"ratio": 0.25}, GRANS[0])
+    t, codec, sched, lays = wire_parts("topk", {"ratio": 0.25}, GRANS[0])
     _, bufs = execute_schedule_wire(sched, codec, None, t, KEY)
     return np.asarray(bufs[0]), lays[0]
 
@@ -304,7 +216,7 @@ def test_parse_message_header_rejects_mutations():
 
 
 # ==========================================================================
-# 5. checkpoints: atomic, digest-verified
+# 4. checkpoints: atomic, digest-verified
 # ==========================================================================
 
 def _ck_tree():
@@ -324,7 +236,7 @@ def test_checkpoint_roundtrip_and_atomicity(tmp_path):
     assert latest_checkpoint(d) == path
     step, got = load_checkpoint(path, like=t)
     assert step == 5
-    _assert_trees_bitwise(got, t, "roundtrip")
+    assert_trees_bitwise(got, t, "roundtrip")
 
 
 def test_checkpoint_rejects_truncation(tmp_path):
@@ -356,7 +268,7 @@ def test_checkpoint_rejects_missing_keys(tmp_path):
 
 
 # ==========================================================================
-# 6. CorruptionSpec / scenario plumbing
+# 5. CorruptionSpec / scenario plumbing
 # ==========================================================================
 
 def test_corruption_spec_validation():
@@ -390,57 +302,9 @@ def test_injector_passthrough_is_same_object():
     assert hop_only.corrupt(buf, KEY, tag=0) is buf  # serialized path
 
 
-# ==========================================================================
-# 7. corruption through the aggregation path: detect / resend / EF
-# ==========================================================================
-
-def _agg_case(name, kw, gran):
-    """Corrupted-with-resend == clean bitwise; EF residuals (sender-side
-    state) stay bitwise clean under receive corruption; detection
-    counters cover every message."""
-    grads = _worker_grads()
-    sm = stacked_mask(_tree())
-    cfg = CompressionConfig(qw=make_compressor(name, **kw),
-                            granularity=gran, error_feedback=True,
-                            integrity=True)
-    ef = init_ef(_tree(), 4)
-    clean_out, clean_ef = aggregate_simulated_workers(
-        grads, sm, cfg, KEY, ef_state=ef, wire=True)
-    spec = CorruptionSpec(prob=1.0, mode="bitflip", n_bits=1, seed=3)
-
-    corrupt = FaultInjector(spec, resend=False)
-    out, new_ef, info = aggregate_simulated_workers(
-        grads, sm, cfg, KEY, ef_state=ef, wire=True, faults=corrupt)
-    assert int(info["messages"]) > 0
-    assert int(info["corrupt_detected"]) == int(info["messages"]), \
-        (name, gran.kind)  # prob=1 single-bit flips: all detected
-    assert int(info["resends"]) == 0
-    # sender-side discipline: EF never sees the receiver's corruption
-    _assert_trees_bitwise(new_ef, clean_ef, (name, gran.kind, "ef"))
-    assert _trees_differ(out, clean_out), (name, gran.kind)
-
-    resend = FaultInjector(spec, resend=True)
-    out_r, ef_r, info_r = aggregate_simulated_workers(
-        grads, sm, cfg, KEY, ef_state=ef, wire=True, faults=resend)
-    assert int(info_r["resends"]) == int(info_r["corrupt_detected"])
-    _assert_trees_bitwise(out_r, clean_out, (name, gran.kind, "resend"))
-    _assert_trees_bitwise(ef_r, clean_ef, (name, gran.kind, "resend-ef"))
-
-
-def test_corruption_resend_smoke():
-    _agg_case("topk", {"ratio": 0.25}, GRANS[0])
-
-
-@pytest.mark.fault
-@pytest.mark.parametrize("gran", GRANS, ids=lambda g: g.kind)
-@pytest.mark.parametrize("name,kw", SIX, ids=[n for n, _ in SIX])
-def test_corruption_resend_zoo(name, kw, gran):
-    _agg_case(name, kw, gran)
-
-
 def test_faults_require_wire():
-    grads = _worker_grads()
-    sm = stacked_mask(_tree())
+    grads = worker_grads()
+    sm = stacked_mask(grad_tree())
     cfg = CompressionConfig(qw=make_compressor("topk", ratio=0.25),
                             granularity=GRANS[0], integrity=True)
     inj = FaultInjector(CorruptionSpec(prob=1.0))
@@ -450,14 +314,14 @@ def test_faults_require_wire():
 
 
 # ==========================================================================
-# 8. partial participation: survivor mean + EF freeze
+# 6. partial participation: survivor mean + EF freeze
 # ==========================================================================
 
 def test_partial_participation_hand_computed():
     """With the identity compressor, the aggregate under an alive mask
     is exactly the plain mean over survivors."""
-    grads = _worker_grads()
-    sm = stacked_mask(_tree())
+    grads = worker_grads()
+    sm = stacked_mask(grad_tree())
     cfg = CompressionConfig(qw=make_compressor("identity"),
                             granularity=GRANS[0])
     alive = np.array([True, False, True, True])
@@ -467,16 +331,16 @@ def test_partial_participation_hand_computed():
     w = w / jnp.sum(w)
     want = jax.tree_util.tree_map(
         lambda g: jnp.tensordot(w, g, axes=1), grads)
-    _assert_trees_bitwise(out, want, "survivor-mean")
+    assert_trees_bitwise(out, want, "survivor-mean")
 
 
 def test_partial_participation_freezes_dead_ef():
-    grads = _worker_grads()
-    sm = stacked_mask(_tree())
+    grads = worker_grads()
+    sm = stacked_mask(grad_tree())
     cfg = CompressionConfig(qw=make_compressor("topk", ratio=0.25),
                             granularity=GRANS[0], error_feedback=True)
     ef = jax.tree_util.tree_map(
-        lambda x: jnp.ones_like(x), init_ef(_tree(), 4))
+        lambda x: jnp.ones_like(x), init_ef(grad_tree(), 4))
     alive = np.array([True, False, True, True])
     _, new_ef = aggregate_simulated_workers(grads, sm, cfg, KEY,
                                             ef_state=ef, alive=alive)
@@ -489,7 +353,7 @@ def test_partial_participation_freezes_dead_ef():
 
 
 # ==========================================================================
-# 9. recovery manager + the resilient training loop
+# 7. recovery manager + the resilient training loop
 # ==========================================================================
 
 class ToyRunner:
@@ -559,8 +423,8 @@ def test_train_resilient_resume_bitwise(tmp_path):
                     ckpt_dir=d, ckpt_every=3)
     resumed = train_resilient(runner, scen, _comp(), steps=6, seed=1,
                               ckpt_dir=d, ckpt_every=3, resume=True)
-    _assert_trees_bitwise(resumed["params"], full["params"], "params")
-    _assert_trees_bitwise(resumed["ef"], full["ef"], "ef")
+    assert_trees_bitwise(resumed["params"], full["params"], "params")
+    assert_trees_bitwise(resumed["ef"], full["ef"], "ef")
     assert resumed["losses"] == full["losses"][3:]
     assert resumed["counters"]["resil/corrupt_detected"] == \
         full["counters"]["resil/corrupt_detected"]
@@ -582,8 +446,8 @@ def test_train_resilient_resend_matches_clean():
     assert faulted["counters"]["resil/corrupt_detected"] > 0
     assert faulted["counters"]["resil/resends"] == \
         faulted["counters"]["resil/corrupt_detected"]
-    _assert_trees_bitwise(faulted["params"], clean["params"], "params")
-    _assert_trees_bitwise(faulted["ef"], clean["ef"], "ef")
+    assert_trees_bitwise(faulted["params"], clean["params"], "params")
+    assert_trees_bitwise(faulted["ef"], clean["ef"], "ef")
     assert faulted["losses"] == clean["losses"]
 
 
@@ -645,7 +509,7 @@ def test_train_resilient_partial_participation():
 
 
 # ==========================================================================
-# 10. engine step-guard + launcher resume
+# 8. engine step-guard + launcher resume
 # ==========================================================================
 
 @pytest.mark.fault
@@ -671,7 +535,7 @@ def test_engine_step_guard_clean_step_identical():
         p2, o2, m2 = guarded(params, opt_state, batch, jnp.int32(0))
     assert float(m2["skipped"]) == 0.0
     assert "skipped" not in m1
-    _assert_trees_bitwise(p1, p2, "step-guard-clean")
+    assert_trees_bitwise(p1, p2, "step-guard-clean")
     assert float(m1["loss"]) == float(m2["loss"])
 
 
@@ -700,25 +564,3 @@ def test_train_launcher_resume_bitwise(tmp_path):
             if k == "__meta__":
                 continue
             assert np.array_equal(np.asarray(za[k]), np.asarray(zc[k])), k
-
-
-# ==========================================================================
-# 11. multi-device ring-hop checks (subprocess)
-# ==========================================================================
-
-@pytest.mark.fault
-@pytest.mark.timeout(1200)
-def test_resil_multidevice_checks():
-    """Drives tests/fault_checks.py on 4 virtual devices: bit flips and
-    dropped hops on a REAL ring are detected and resend recovers the
-    clean bits; a duplicated (stale) hop passes the checksum — the
-    documented sequence-number gap."""
-    script = os.path.join(os.path.dirname(__file__), "fault_checks.py")
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    res = subprocess.run([sys.executable, script], capture_output=True,
-                         text=True, env=env, timeout=1200)
-    sys.stdout.write(res.stdout[-4000:])
-    sys.stderr.write(res.stderr[-4000:])
-    assert res.returncode == 0, "fault checks failed"
-    assert "ALL FAULT CHECKS PASSED" in res.stdout

@@ -29,27 +29,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _sweep_helpers import (GRANS, KEY, SIX, assert_trees_bitwise, grad_tree,
+                            sweep, worker_grads)
 from repro.core import (CompressionConfig, Granularity,
                         aggregate_simulated_workers, build_plan,
-                        make_compressor, simulate_schedule, build_schedule,
-                        stacked_mask)
+                        make_compressor, stacked_mask)
 from repro.data import (dirichlet_proportions, noniid_classification_batch,
                         noniid_markov_lm_batch, make_markov)
 from repro.sim import (LinkSpec, RescaleEvent, Scenario, SimCluster,
                        StragglerSpec, init_ef)
 
-KEY = jax.random.key(0)
-
-SIX = [
-    ("topk", {"ratio": 0.25}),
-    ("randomk", {"ratio": 0.3, "scale": True}),
-    ("qsgd", {"levels": 16}),
-    ("terngrad", {}),
-    ("signsgd", {}),
-    ("natural", {}),
-]
-
-GRANS = [Granularity("layerwise"), Granularity("entire_model")]
 
 #: every knob present, every knob at its identity setting — the hostile
 #: shape of the spec with the clean semantics of the default.
@@ -59,57 +48,27 @@ IDENTITY = Scenario(
     rescales=(RescaleEvent(step=3, world_size=4),))
 
 
-def _tree(key=KEY):
-    ks = [jax.random.fold_in(key, i) for i in range(5)]
-    return {"blocks": {"w": jax.random.normal(ks[0], (3, 16, 8)),
-                       "b": jax.random.normal(ks[1], (3, 8))},
-            "embed": jax.random.normal(ks[2], (20, 4)),
-            "head": jax.random.normal(ks[3], (4, 2)),
-            "scalar_gain": jax.random.normal(ks[4], ())}
-
-
-def _worker_grads(n=4, key=KEY):
-    """Per-worker gradient stack: leading worker axis, distinct draws."""
-    trees = [_tree(jax.random.fold_in(key, 100 + i)) for i in range(n)]
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
-
-
-def _assert_trees_bitwise(a, b, ctx):
-    for la, lb in zip(jax.tree_util.tree_leaves(a),
-                      jax.tree_util.tree_leaves(b)):
-        assert la.shape == lb.shape and la.dtype == lb.dtype, ctx
-        assert bool((la == lb).all()), (
-            ctx, float(jnp.max(jnp.abs(la - lb))))
-
-
-def _identity_case(name, kw, gran, wire):
-    grads = _worker_grads()
-    stacked = stacked_mask(_tree())
+@pytest.mark.parametrize("name,kw,gran,wire", sweep(
+    [(name, kw, gran, wire) for wire in ("sim", "wire") for name, kw in SIX
+     for gran in GRANS],
+    lambda name, kw, gran, wire: name == "topk" and wire == "wire",
+    pytest.mark.scenario))
+def test_identity_scenario_bitwise_zoo(name, kw, gran, wire):
+    """The unmarked subset: topk + EF + wire at both granularities."""
+    on_wire = wire == "wire"
+    grads = worker_grads()
+    stacked = stacked_mask(grad_tree())
     cfg = CompressionConfig(qw=make_compressor(name, **kw),
                             granularity=gran, error_feedback=True)
-    ef = init_ef(_tree(), 4)
+    ef = init_ef(grad_tree(), 4)
     cluster = SimCluster(IDENTITY, cfg)
     assert IDENTITY.is_identity()
-    got = cluster.aggregate(grads, stacked, KEY, ef_state=ef, wire=wire)
+    got = cluster.aggregate(grads, stacked, KEY, ef_state=ef, wire=on_wire)
     want = aggregate_simulated_workers(grads, stacked, cfg, KEY,
-                                       ef_state=ef, wire=wire)
+                                       ef_state=ef, wire=on_wire)
     ctx = (name, gran.kind, wire)
-    _assert_trees_bitwise(got[0], want[0], ctx)
-    _assert_trees_bitwise(got[1], want[1], ctx)
-
-
-def test_identity_scenario_smoke():
-    """Inner-loop subset: topk + EF + wire at both granularities."""
-    for gran in GRANS:
-        _identity_case("topk", {"ratio": 0.25}, gran, wire=True)
-
-
-@pytest.mark.scenario
-@pytest.mark.parametrize("gran", GRANS, ids=lambda g: g.kind)
-@pytest.mark.parametrize("name,kw", SIX, ids=[n for n, _ in SIX])
-@pytest.mark.parametrize("wire", [False, True], ids=["sim", "wire"])
-def test_identity_scenario_bitwise_zoo(name, kw, gran, wire):
-    _identity_case(name, kw, gran, wire)
+    assert_trees_bitwise(got[0], want[0], ctx)
+    assert_trees_bitwise(got[1], want[1], ctx)
 
 
 # ==========================================================================
@@ -119,7 +78,7 @@ def test_identity_scenario_bitwise_zoo(name, kw, gran, wire):
 def _int_ef(n=4):
     """Integer-valued residuals: fp addition on small ints is exact, so
     conservation sums are EQUALITY checks, not tolerances."""
-    tree = _tree()
+    tree = grad_tree()
     i = [0]
 
     def fill(p):
@@ -135,9 +94,9 @@ def test_rescale_to_same_size_is_bitwise_noop(tmp_path):
     cluster = SimCluster(IDENTITY, cfg, ckpt_dir=str(tmp_path))
     ef = jax.tree_util.tree_map(
         lambda p: jax.random.normal(jax.random.fold_in(KEY, 7),
-                                    (4,) + p.shape), _tree())
+                                    (4,) + p.shape), grad_tree())
     back = cluster.rescale_ef(ef, 4, step=0)
-    _assert_trees_bitwise(back, ef, "n->n rescale through ckpt")
+    assert_trees_bitwise(back, ef, "n->n rescale through ckpt")
 
 
 def test_ef_conservation_4_2_4(tmp_path):
@@ -274,7 +233,7 @@ def test_per_link_fusion_policy_fuses_on_high_alpha_links():
     a zero-latency link under the same layerwise plan — the per-link
     FusionPolicy decision the accounting prices."""
     qw = make_compressor("topk", ratio=0.25)
-    tree = _tree()
+    tree = grad_tree()
     plan = build_plan(tree, stacked_mask(tree), Granularity("layerwise"))
     sc = Scenario(name="fuse", n_workers=2,
                   links=(LinkSpec(alpha_us=0.0, gbps=12.5),
@@ -310,7 +269,7 @@ def test_noniid_classification_batch_deterministic_and_skewed():
     props = dirichlet_proportions(key, 4, 10, alpha=0.05)
     b1 = noniid_classification_batch(jax.random.fold_in(key, 1), props, 32)
     b2 = noniid_classification_batch(jax.random.fold_in(key, 1), props, 32)
-    _assert_trees_bitwise(b1, b2, "noniid classification determinism")
+    assert_trees_bitwise(b1, b2, "noniid classification determinism")
     assert b1["images"].shape == (4, 32, 32, 32, 3)
     assert b1["labels"].shape == (4, 32)
     labels = np.asarray(b1["labels"])
@@ -329,7 +288,7 @@ def test_noniid_lm_batch_deterministic():
                                 8, 16)
     b2 = noniid_markov_lm_batch(jax.random.fold_in(key, 2), trans, props,
                                 8, 16)
-    _assert_trees_bitwise(b1, b2, "noniid lm determinism")
+    assert_trees_bitwise(b1, b2, "noniid lm determinism")
     assert b1["tokens"].shape == (4, 8, 16)
     assert bool((b1["targets"][:, :, :-1] == b1["tokens"][:, :, 1:]).all())
 

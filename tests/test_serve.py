@@ -76,6 +76,6 @@ def test_serve_batched_example_runs():
     proc = subprocess.run(
         [sys.executable, os.path.join(_REPO, "examples",
                                       "serve_batched.py")],
-        capture_output=True, text=True, env=env, timeout=540)
+        capture_output=True, text=True, env=env, timeout=60)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "served 8 sequences" in proc.stdout

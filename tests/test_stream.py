@@ -1,4 +1,4 @@
-"""Streaming ring collectives: the differential suite.
+"""Streaming ring collectives: the collective-path contracts.
 
 The correctness contract: `CommSchedule.execute_streaming` (chunked-
 ppermute ring, mode='ring') is BIT-identical to the serialized allgather
@@ -6,171 +6,41 @@ wire path — same packed payloads, same decode-then-mean in the same
 worker order — for every codec, granularity, fusion threshold and
 chunking, including threaded error feedback; and `rs_stream` (compress →
 reduce-scatter → allgather) degenerates to exactly the same contract at
-one worker. The single-device sweep here holds that differential; the
-genuinely-multi-worker properties (ring == allgather at n=4, the
-double-buffer jaxpr interleave proof, per-hop span counts, rs padding on
-non-divisible dims, `_mean_psum` static-n bit-identity) run in a
-4-virtual-device subprocess (tests/stream_checks.py — XLA device count
-must be set before jax initializes).
+one worker. The single-device sweeps holding that differential are in
+tests/test_stream_allgather_{quantizers,fields}.py and
+tests/test_stream_ef.py; the genuinely-multi-worker properties (ring ==
+allgather at n=4, the double-buffer jaxpr interleave proof, per-hop span
+counts, rs padding on non-divisible dims, `_mean_psum` static-n
+bit-identity) run in a 4-virtual-device subprocess
+(tests/stream_checks.py — XLA device count must be set before jax
+initializes).
 
-Also here: the collective-path bugfix regressions this PR's streaming
-work flushed out — rs bits accounted on the true d (hand-computed
-non-divisible case), `_mean_psum`'s psum-of-ones collective dropped from
-every message, and `fit_alpha_beta`'s degenerate-fit clamp.
+Also here: the engine routing its step through the ring, and the
+collective-path bugfix regressions the streaming work flushed out — rs
+bits accounted on the true d (hand-computed non-divisible case),
+`_mean_psum`'s psum-of-ones collective dropped from every message, and
+`fit_alpha_beta`'s degenerate-fit clamp.
 
-The full sweep carries the `stream` marker: tier-1 (`make verify`) only,
-excluded from the `make verify-fast` inner loop.
+The `stream`-marked tests run in tier-1 (`make verify`) only, excluded
+from the `make verify-fast` inner loop.
 """
 import math
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.core import (CompressionConfig, FUSE_ALL, Granularity,
-                        build_plan, comm_report, compressed_allreduce,
-                        make_compressor, stacked_mask)
+from _sweep_helpers import KEY, assert_steps_bitwise, grad_tree, smoke_engine
+from repro.core import (CompressionConfig, Granularity, build_plan,
+                        comm_report, compressed_allreduce, make_compressor,
+                        stacked_mask)
 from repro.launch.engine import shard_map
 from repro.launch.mesh import make_host_mesh
 
-KEY = jax.random.key(0)
-
-THRESHOLDS = (0.0, float(1 << 16), FUSE_ALL)
-
-SIX = [
-    ("topk", {"ratio": 0.25}),
-    ("randomk", {"ratio": 0.3, "scale": True}),
-    ("qsgd", {"levels": 16}),
-    ("terngrad", {}),
-    ("signsgd", {}),
-    ("natural", {}),
-]
-
-GRANS = [Granularity("layerwise"), Granularity("entire_model")]
-
-
-def _tree(key=KEY):
-    ks = [jax.random.fold_in(key, i) for i in range(5)]
-    return {"blocks": {"w": jax.random.normal(ks[0], (3, 16, 8)),
-                       "b": jax.random.normal(ks[1], (3, 8))},
-            "embed": jax.random.normal(ks[2], (20, 4)),
-            "head": jax.random.normal(ks[3], (4, 2)),
-            "scalar_gain": jax.random.normal(ks[4], ())}
-
-
-def _assert_trees_bitwise(a, b, ctx):
-    for la, lb in zip(jax.tree_util.tree_leaves(a),
-                      jax.tree_util.tree_leaves(b)):
-        assert la.shape == lb.shape and la.dtype == lb.dtype, ctx
-        assert bool((la == lb).all()), (
-            ctx, float(jnp.max(jnp.abs(la - lb))))
-
-
-def _collective_fn(cfg, ef: bool, chunk=None):
-    """jitted shard_map'd compressed_allreduce on a 1-worker mesh — the
-    in-process realization where ring/rs_stream must reproduce the
-    allgather wire path exactly (rs_stream's shard partition degenerates
-    to the whole unit at n=1)."""
-    t = _tree()
-    sm = stacked_mask(t)
-    mesh = make_host_mesh(1, 1)
-
-    if ef:
-        def f(g, m, key):
-            return compressed_allreduce(g, sm, cfg, ("data",), key, 1,
-                                        wire=True, ef_state=m,
-                                        stream_chunk_bytes=chunk)
-        return t, jax.jit(shard_map(f, mesh, in_specs=(P(), P(), P()),
-                                    out_specs=(P(), P())))
-
-    def f(g, key):
-        out, _ = compressed_allreduce(g, sm, cfg, ("data",), key, 1,
-                                      wire=True, stream_chunk_bytes=chunk)
-        return out
-    return t, jax.jit(shard_map(f, mesh, in_specs=(P(), P()),
-                                out_specs=P()))
-
-
-def _run_once(strat, qw, gran, fb, chunk=None):
-    cfg = CompressionConfig(qw=qw, granularity=gran, strategy=strat,
-                            fusion_bytes=fb)
-    t, fn = _collective_fn(cfg, ef=False, chunk=chunk)
-    return fn(t, KEY)
-
-
-def _run_ef_steps(strat, qw, gran, fb, chunk=None, steps=5):
-    cfg = CompressionConfig(qw=qw, granularity=gran, strategy=strat,
-                            error_feedback=True, fusion_bytes=fb)
-    t, fn = _collective_fn(cfg, ef=True, chunk=chunk)
-    m = jax.tree_util.tree_map(jnp.zeros_like, t)
-    outs = []
-    for i in range(steps):
-        g = jax.tree_util.tree_map(lambda x: x * (1.0 + 0.1 * i), t)
-        out, m = fn(g, m, jax.random.fold_in(KEY, i))
-        outs.append(out)
-    return outs, m
-
-
-# ---------------------------------------------------------------------------
-# streaming == serialized allgather wire path, bit for bit
-# ---------------------------------------------------------------------------
-
-def test_stream_matches_allgather_smoke():
-    """Inner-loop subset of the full `stream` sweep: two operators x
-    layerwise x {no fusion, full fusion} x both streaming strategies."""
-    gran = Granularity("layerwise")
-    for name, kw in (("qsgd", {"levels": 16}), ("topk", {"ratio": 0.25})):
-        qw = make_compressor(name, **kw)
-        for fb in (0.0, FUSE_ALL):
-            ref = _run_once("allgather", qw, gran, fb)
-            for strat in ("ring", "rs_stream"):
-                got = _run_once(strat, qw, gran, fb)
-                _assert_trees_bitwise(ref, got, (name, fb, strat))
-
-
-@pytest.mark.stream
-@pytest.mark.parametrize("name,kw", SIX)
-def test_stream_matches_allgather_full(name, kw):
-    """The acceptance sweep: all six codecs x {layerwise, entire_model}
-    x fusion {0, 64KiB, inf} x {ring, rs_stream} — bit-identical to the
-    serialized allgather wire path (chunked hops exercised on the fused
-    one-shot message, where chunks are real)."""
-    qw = make_compressor(name, **kw)
-    for gran in GRANS:
-        for fb in THRESHOLDS:
-            ref = _run_once("allgather", qw, gran, fb)
-            chunk = 64.0 if fb == FUSE_ALL else None
-            for strat in ("ring", "rs_stream"):
-                got = _run_once(strat, qw, gran, fb, chunk=chunk)
-                _assert_trees_bitwise(ref, got,
-                                      (name, gran.kind, fb, strat))
-
-
-@pytest.mark.stream
-@pytest.mark.parametrize("name,kw", SIX)
-def test_stream_ef_conservation_full(name, kw):
-    """5 steps of Algorithm 1 with threaded error feedback: the
-    streaming paths' outputs AND residual memories stay bit-identical to
-    the serialized wire path at every step — EF is neither dropped nor
-    double-applied by the ring reordering."""
-    qw = make_compressor(name, **kw)
-    gran = Granularity("layerwise")
-    for fb in (0.0, FUSE_ALL):
-        ref_outs, ref_m = _run_ef_steps("allgather", qw, gran, fb)
-        for strat in ("ring", "rs_stream"):
-            got_outs, got_m = _run_ef_steps(strat, qw, gran, fb,
-                                            chunk=64.0)
-            for i, (r, g) in enumerate(zip(ref_outs, got_outs)):
-                _assert_trees_bitwise(r, g, (name, fb, strat, "step", i))
-            _assert_trees_bitwise(ref_m, got_m, (name, fb, strat, "m"))
-
 
 def test_stream_requires_wire_and_single_axis():
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     cfg = CompressionConfig(qw=make_compressor("qsgd", levels=16),
                             granularity=Granularity("layerwise"),
@@ -194,21 +64,14 @@ def test_stream_requires_wire_and_single_axis():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.stream
-@pytest.mark.timeout(1200)
-def test_stream_multidevice_checks():
+@pytest.mark.timeout(630)
+def test_stream_multidevice_checks(run_check_script):
     """Drives tests/stream_checks.py: ring == allgather bitwise at n=4
     (incl. 5-step EF), the double-buffer jaxpr interleave proof, per-hop
     span counts, rs padding on non-divisible dims, `_mean_psum`
     static-n bit-identity."""
-    script = os.path.join(os.path.dirname(__file__), "stream_checks.py")
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    res = subprocess.run([sys.executable, script], capture_output=True,
-                         text=True, env=env, timeout=1200)
-    sys.stdout.write(res.stdout[-4000:])
-    sys.stderr.write(res.stderr[-4000:])
-    assert res.returncode == 0, "stream checks failed"
-    assert "ALL STREAM CHECKS PASSED" in res.stdout
+    run_check_script("stream_checks.py", "ALL STREAM CHECKS PASSED",
+                     timeout=600)
 
 
 # ---------------------------------------------------------------------------
@@ -220,30 +83,11 @@ def test_engine_collective_ring_bit_identical():
     """build_train_step(collective='ring') is bit-for-bit the
     collective='allgather' step — the streaming ring behind the engine
     changes program order, never numerics."""
-    from repro.configs.registry import get_smoke
-    from repro.launch.engine import Engine
-
-    cfg = get_smoke("mamba2-1.3b")
-    mesh = make_host_mesh(1, 1)
-    comp = CompressionConfig(qw=make_compressor("qsgd", levels=16),
-                             granularity=Granularity("layerwise"))
-    eng = Engine(cfg, mesh, comp=comp)
-    batch = {"tokens": jnp.ones((4, 16), jnp.int32) * 3,
-             "targets": jnp.ones((4, 16), jnp.int32) * 5}
-
-    def run(step_fn):
-        params, opt_state = eng.init_state(0)
-        for i in range(2):
-            params, opt_state, m = step_fn(params, opt_state, batch,
-                                           jnp.int32(i))
-        return params, m
-
-    p_ref, m_ref = run(eng.build_train_step(wire=True,
-                                            collective="allgather"))
-    p_ring, m_ring = run(eng.build_train_step(wire=True,
-                                              collective="ring"))
-    _assert_trees_bitwise(p_ref, p_ring, "engine-collective-ring")
-    assert float(m_ref["loss"]) == float(m_ring["loss"])
+    eng = smoke_engine()
+    assert_steps_bitwise(
+        eng, eng.build_train_step(wire=True, collective="allgather"),
+        eng.build_train_step(wire=True, collective="ring"),
+        "engine-collective-ring")
 
 
 def test_engine_collective_validation():
@@ -322,7 +166,7 @@ def test_mean_psum_static_drops_collective():
     dispatch, where the legacy psum-of-ones mean carried two — one
     whole collective per message gone, proven on the jaxpr."""
     from repro.core.aggregation import _mean_psum
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     qw = make_compressor("qsgd", levels=16)
     plan = build_plan(t, sm, Granularity("layerwise"))

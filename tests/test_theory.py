@@ -22,7 +22,7 @@ def test_lemma1_inequality_chain():
     assert mid <= rhs + 1e-6
 
 
-@settings(max_examples=30, deadline=None)
+@settings(max_examples=30)
 @given(st.lists(st.floats(min_value=0.0, max_value=50.0), min_size=2,
                 max_size=12),
        st.lists(st.floats(min_value=0.0, max_value=50.0), min_size=2,

@@ -13,8 +13,11 @@ Two load-bearing properties:
      the analytic accounting can never silently drift from the bytes a
      deployment would put on the links again.
 
-The full sweeps carry the `wire` marker (tier-1 only; `make verify-fast`
-keeps the unmarked smoke subset).
+Both held here per unit; tests/test_wire_differential.py holds them
+across granularities and fusion thresholds, tests/test_wire_execution.py
+across error feedback, the collective strategies and the engine step.
+The `wire`-marked tests run in tier-1 only (excluded by `make
+verify-fast`).
 """
 import math
 
@@ -23,50 +26,14 @@ import jax.numpy as jnp
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (CompressionConfig, FUSE_ALL, Granularity,
-                        aggregate_simulated_workers, build_plan,
+from _sweep_helpers import KEY, SIX, assert_trees_bitwise, grad_tree
+from repro.core import (CompressionConfig, Granularity, build_plan,
                         build_schedule, comm_report, compressed_allreduce,
                         index_bits, make_compressor,
                         measured_bits_from_payloads, message_layouts,
                         stacked_mask, wire_codec, word_padding)
 from repro.core.compressors import _k_of
 from repro.core.wire import has_wire_codec
-
-KEY = jax.random.key(0)
-
-# the paper's six operators (ISSUE: "all six compressors"), one codec each
-SIX = [
-    ("topk", {"ratio": 0.25}),
-    ("randomk", {"ratio": 0.3, "scale": True}),
-    ("qsgd", {"levels": 16}),
-    ("terngrad", {}),
-    ("signsgd", {}),
-    ("natural", {}),
-]
-
-GRANS = [Granularity("layerwise"), Granularity("entire_model")]
-
-# ISSUE fusion matrix: per-bucket messages, 64 KiB buffers, one message
-THRESHOLDS = (0.0, float(1 << 16), FUSE_ALL)
-
-
-def _tree(key=KEY):
-    """Mixed pytree: scan-stacked + loose leaves of several size classes
-    (odd dims exercise word-boundary padding)."""
-    ks = [jax.random.fold_in(key, i) for i in range(5)]
-    return {"blocks": {"w": jax.random.normal(ks[0], (3, 16, 8)),
-                       "b": jax.random.normal(ks[1], (3, 8))},
-            "embed": jax.random.normal(ks[2], (20, 4)),
-            "head": jax.random.normal(ks[3], (4, 2)),
-            "scalar_gain": jax.random.normal(ks[4], ())}
-
-
-def _assert_trees_bitwise(a, b, ctx):
-    for la, lb in zip(jax.tree_util.tree_leaves(a),
-                      jax.tree_util.tree_leaves(b)):
-        assert la.shape == lb.shape and la.dtype == lb.dtype, ctx
-        assert bool((la == lb).all()), (
-            ctx, float(jnp.max(jnp.abs(la - lb))))
 
 
 def _packed_leg_bits(name, kw, d):
@@ -100,11 +67,16 @@ def test_roundtrip_bitexact(name, kw):
         assert payload.dtype == jnp.uint8
         assert payload.shape == (codec.nbytes(d),)
         y = codec.decode(payload, d)
-        _assert_trees_bitwise(y, c.sim(x, KEY), (name, d))
+        assert_trees_bitwise(y, c.sim(x, KEY), (name, d))
 
 
-@settings(max_examples=30, deadline=None)
-@given(st.integers(min_value=1, max_value=1025),
+#: payload sizes: one element, either side of a uint32 word and of a
+#: 512-lane row, odd sizes, and the largest size
+ROUNDTRIP_DIMS = [1, 31, 32, 33, 100, 511, 512, 513, 777, 1025]
+
+
+@settings(max_examples=12)
+@given(st.sampled_from(ROUNDTRIP_DIMS),
        st.integers(min_value=0, max_value=10_000),
        st.sampled_from([s[0] for s in SIX]),
        st.sampled_from([0.03, 0.25, 0.9]))
@@ -117,7 +89,7 @@ def test_property_roundtrip_bitexact(d, seed, name, ratio):
     key = jax.random.key(seed)
     x = jax.random.normal(key, (d,)) * 3.0
     y = codec.decode(codec.encode(x, key), d)
-    _assert_trees_bitwise(y, c.sim(x, key), (name, d, ratio))
+    assert_trees_bitwise(y, c.sim(x, key), (name, d, ratio))
 
 
 def test_threshold_codecs_are_the_theory_practice_gap():
@@ -127,7 +99,7 @@ def test_threshold_codecs_are_the_theory_practice_gap():
     allgather wire), is flagged exact_sim=False, and the simulated-
     strategy wire path refuses it instead of silently changing numerics.
     """
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     for name in ("threshold_v", "adaptive_threshold"):
         c = make_compressor(name)
@@ -135,7 +107,7 @@ def test_threshold_codecs_are_the_theory_practice_gap():
         assert codec.exact_sim is False
         x = jax.random.normal(KEY, (100,))
         y = codec.decode(codec.encode(x, KEY), 100)
-        _assert_trees_bitwise(y, c.decode(c.encode(x, KEY), 100), name)
+        assert_trees_bitwise(y, c.decode(c.encode(x, KEY), 100), name)
         cfg = CompressionConfig(qw=c, granularity=Granularity("layerwise"),
                                 strategy="simulated")
         with pytest.raises(ValueError, match="capacity-bounded"):
@@ -175,7 +147,7 @@ def test_dense_codec_has_zero_padding():
 def test_comm_report_measured_flag():
     """comm_report(measured=True) - comm_report() == the summed per-unit
     padding slack — the accounting and the wire agree exactly."""
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     plan = build_plan(t, sm, Granularity("layerwise"))
     for name, kw in SIX:
@@ -190,162 +162,6 @@ def test_comm_report_measured_flag():
             acct.uplink_bits_per_worker + slack, name
         assert meas.downlink_bits_per_worker == \
             acct.downlink_bits_per_worker + 3 * slack, name
-
-
-# ---------------------------------------------------------------------------
-# the differential suite: executed fused messages vs the accounting
-# ---------------------------------------------------------------------------
-
-def _check_differential(name, kw, gran, fb):
-    t = _tree()
-    sm = stacked_mask(t)
-    c = make_compressor(name, **kw)
-    codec = wire_codec(c)
-    plan = build_plan(t, sm, gran)
-    sched = build_schedule(plan, fb)
-
-    # numerics: wire streaming == the unscheduled unpacked reference
-    ref = plan.execute(lambda x, k: c.sim(x, k), t, KEY)
-    got, bufs = sched.execute(None, t, KEY, wire=codec)
-    _assert_trees_bitwise(ref, got, (name, gran.kind, fb))
-
-    # wire truth: executed buffer bytes == static layouts == accounting
-    layouts = message_layouts(sched, codec)
-    assert len(bufs) == sched.num_messages
-    for buf, lay in zip(bufs, layouts):
-        assert buf.size == lay.total_nbytes
-        # the header is readable back out of the buffer
-        header = jax.lax.bitcast_convert_type(
-            buf[:lay.header_nbytes].reshape(-1, 4), jnp.uint32)
-        assert int(header[0]) == len(lay.bucket_ids)
-        assert tuple(int(v) for v in header[1:]) == lay.offsets
-    measured = measured_bits_from_payloads(bufs)
-    header_bits = 8 * sum(l.header_nbytes for l in layouts)
-    payload_bits = 8 * sum(l.payload_nbytes for l in layouts)
-    assert measured == payload_bits + header_bits
-
-    # accounted == measured payload, exactly (modulo documented padding)
-    cfg = CompressionConfig(qw=c, granularity=gran, strategy="allgather")
-    acct = comm_report(cfg, plan, 2).uplink_bits_per_worker
-    slack = sum(codec.padding_bits(d) for d in plan.unit_dims)
-    assert payload_bits == acct + slack, (name, gran.kind, fb)
-    assert payload_bits == comm_report(
-        cfg, plan, 2, measured=True).uplink_bits_per_worker
-
-
-def test_differential_smoke():
-    """Inner-loop subset of the full `wire`-marked sweep."""
-    for name, kw in (("qsgd", {"levels": 16}), ("topk", {"ratio": 0.25})):
-        for fb in (0.0, FUSE_ALL):
-            _check_differential(name, kw, Granularity("layerwise"), fb)
-
-
-@pytest.mark.wire
-@pytest.mark.parametrize("name,kw", SIX)
-def test_differential_full(name, kw):
-    """The acceptance sweep: all six compressors x {layerwise,
-    entire_model} x fusion {0, 64KiB, inf} — accounted payload bits ==
-    measured packed bytes, and wire numerics == unpacked numerics,
-    everywhere."""
-    for gran in GRANS:
-        for fb in THRESHOLDS:
-            _check_differential(name, kw, gran, fb)
-
-
-# ---------------------------------------------------------------------------
-# wire execution == unpacked execution through the aggregation stack
-# ---------------------------------------------------------------------------
-
-def _run_ef_steps(name, kw, wire, fusion_bytes=None, steps=5):
-    t = _tree()
-    sm = stacked_mask(t)
-    n = 2
-    cfg = CompressionConfig(qw=make_compressor(name, **kw),
-                            granularity=Granularity("layerwise"),
-                            error_feedback=True,
-                            fusion_bytes=fusion_bytes)
-    ef = jax.tree_util.tree_map(
-        lambda x: jnp.zeros((n,) + x.shape, jnp.float32), t)
-    out = None
-    for i in range(steps):
-        wg = jax.tree_util.tree_map(
-            lambda x: jnp.stack([x * (1.0 + 0.1 * i), -0.5 * x]), t)
-        out, ef = aggregate_simulated_workers(
-            wg, sm, cfg, jax.random.fold_in(KEY, i), ef_state=ef,
-            wire=wire)
-    return out, ef
-
-
-def test_wire_matches_unpacked_ef_smoke():
-    """5 steps of Algorithm 1 with error-feedback threading: the wire
-    path's outputs AND residual memories stay bit-identical."""
-    ref = _run_ef_steps("topk", {"ratio": 0.1}, wire=False)
-    got = _run_ef_steps("topk", {"ratio": 0.1}, wire=True)
-    _assert_trees_bitwise(ref, got, "ef-wire-smoke")
-
-
-@pytest.mark.wire
-@pytest.mark.parametrize("name,kw", SIX)
-def test_wire_matches_unpacked_ef_full(name, kw):
-    """All six compressors x 5 EF steps x {per-bucket, fused} wire
-    messages: bit-identical to the unpacked path."""
-    ref = _run_ef_steps(name, kw, wire=False)
-    for fb in (None, FUSE_ALL):
-        got = _run_ef_steps(name, kw, wire=True, fusion_bytes=fb)
-        _assert_trees_bitwise(ref, got, (name, fb))
-
-
-def test_collective_wire_paths_bit_identical():
-    """compressed_allreduce inside shard_map: wire=True matches the
-    unpacked path for BOTH strategies — under `allgather` the packed
-    uint8 payload itself crosses the collective."""
-    from jax.sharding import PartitionSpec as P
-    from repro.launch.engine import shard_map
-    from repro.launch.mesh import make_host_mesh
-    t = _tree()
-    sm = stacked_mask(t)
-    mesh = make_host_mesh(1, 1)
-    for strat in ("simulated", "allgather"):
-        cfg = CompressionConfig(qw=make_compressor("qsgd", levels=16),
-                                granularity=Granularity("layerwise"),
-                                strategy=strat)
-
-        def run(wire):
-            def f(g):
-                out, _ = compressed_allreduce(g, sm, cfg, ("data",), KEY,
-                                              1, wire=wire)
-                return out
-            return jax.jit(shard_map(f, mesh, in_specs=(P(),),
-                                     out_specs=P()))(t)
-
-        _assert_trees_bitwise(run(False), run(True), strat)
-
-
-def test_engine_wire_step_bit_identical():
-    """Acceptance: the sharded train step with wire=True is bit-for-bit
-    the unpacked step (real message buffers in the compiled graph)."""
-    from repro.configs.registry import get_smoke
-    from repro.launch.engine import Engine
-    from repro.launch.mesh import make_host_mesh
-    cfg = get_smoke("mamba2-1.3b")
-    mesh = make_host_mesh(1, 1)
-    comp = CompressionConfig(qw=make_compressor("qsgd", levels=16),
-                             granularity=Granularity("layerwise"))
-    eng = Engine(cfg, mesh, comp=comp)
-    batch = {"tokens": jnp.ones((4, 16), jnp.int32) * 3,
-             "targets": jnp.ones((4, 16), jnp.int32) * 5}
-
-    def run(step_fn):
-        params, opt_state = eng.init_state(0)
-        for i in range(2):
-            params, opt_state, m = step_fn(params, opt_state, batch,
-                                           jnp.int32(i))
-        return params, m
-
-    p_ref, m_ref = run(eng.build_train_step())
-    p_w, m_w = run(eng.build_train_step(wire=True))
-    _assert_trees_bitwise(p_ref, p_w, "engine-wire")
-    assert float(m_ref["loss"]) == float(m_w["loss"])
 
 
 # ---------------------------------------------------------------------------
@@ -365,7 +181,7 @@ def test_signsgd_majority_vote_on_packed_words():
         maj = codec.decode(codec.majority_vote(payloads, d), d)
         signs = jnp.where(xs >= 0, 1.0, -1.0)
         dense = jnp.where(jnp.sum(signs, axis=0) >= 0, 1.0, -1.0)
-        _assert_trees_bitwise(maj, dense, n)
+        assert_trees_bitwise(maj, dense, n)
 
 
 def test_pallas_pack_kernels_match_oracle():
@@ -394,7 +210,7 @@ def test_pallas_pack_kernels_match_oracle():
 def test_pallas_codec_entire_model():
     """The Pallas codec through the 1-unit entire-model schedule (the
     non-vmapped hot path): still bit-identical to sim."""
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     c = make_compressor("qsgd", levels=16)
     codec = wire_codec(c)
@@ -402,7 +218,7 @@ def test_pallas_codec_entire_model():
     sched = build_schedule(plan, 0.0)
     ref = plan.execute(lambda x, k: c.sim(x, k), t, KEY)
     got, bufs = sched.execute(None, t, KEY, wire=codec)
-    _assert_trees_bitwise(ref, got, "pallas-codec")
+    assert_trees_bitwise(ref, got, "pallas-codec")
     assert measured_bits_from_payloads(bufs) == \
         8 * message_layouts(sched, codec)[0].total_nbytes
 
@@ -413,7 +229,7 @@ def test_telemetry_wire_bits_leg():
     measured one and the two differ by exactly the padding slack."""
     from repro.control.telemetry import (measure, measurement_plan,
                                          payload_bits_per_step, summarize)
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     mplan = measurement_plan(t, sm)
     qw = make_compressor("signsgd")
@@ -430,7 +246,7 @@ def test_telemetry_wire_bits_leg():
 
 
 def test_wire_refuses_unwireable_configs():
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     cfg = CompressionConfig(qw=make_compressor("randomk", ratio=0.1),
                             strategy="shared_random")
@@ -536,7 +352,7 @@ def test_bf16_collective_matches_f32_path_cast():
     from jax.sharding import PartitionSpec as P
     from repro.launch.engine import shard_map
     from repro.launch.mesh import make_host_mesh
-    t = _tree()
+    t = grad_tree()
     sm = stacked_mask(t)
     mesh = make_host_mesh(1, 1)
     qw = make_compressor("topk", ratio=0.25)
@@ -563,7 +379,7 @@ def test_bf16_cast_helpers_round_trip_exact_on_bf16_grid():
     """to_f32(to_bf16(x)) is exact when x already sits on the bf16 grid
     (the idiom's contract: casting down then up is a projection)."""
     from repro.core import to_bf16, to_f32
-    t = _tree()
+    t = grad_tree()
     once = to_f32(to_bf16(t))
     twice = to_f32(to_bf16(once))
-    _assert_trees_bitwise(once, twice, "bf16 projection idempotent")
+    assert_trees_bitwise(once, twice, "bf16 projection idempotent")
