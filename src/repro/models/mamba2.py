@@ -2,7 +2,9 @@
 
 Chunked SSD algorithm: within a chunk the recurrence is computed as a
 masked quadratic form (MXU-friendly); across chunks a small lax.scan carries
-the (heads, head_dim, state) SSM state. Heads and inner channels are
+the (heads, head_dim, state) SSM state. On TPU, where the shapes tile, the
+same algorithm runs as one Pallas kernel pair (`kernels/ssd.py`) that keeps
+each chunk's scores and the carried state in VMEM. Heads and inner channels are
 TP-sharded; B/C projections are group-shared (G=1 ⇒ MQA-like) and therefore
 TP-replicated with tp_shared grad sync.
 
@@ -20,6 +22,8 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
+from repro.kernels import ssd as ssd_kernel
 from repro.models.dist import (DistConfig, region_in, region_out,
                                tp_region_in, tp_region_out, tp_shared)
 from repro.models.layers import rmsnorm
@@ -60,9 +64,20 @@ def ssd_chunked(xh: Array, dt: Array, A: Array, Bm: Array, Cm: Array,
     xh (B,S,H,P) values; dt (B,S,H) softplus'd step; A (H,) negative;
     Bm/Cm (B,S,N) group-shared input/output projections; D (H,) skip.
     Returns (y (B,S,H,P), final_state (B,H,P,N)).
+
+    Compiled for TPU with shapes the kernel pair tiles, this runs
+    `kernels.ssd.ssd`; otherwise (and on CPU, where this jnp form is the
+    kernels' oracle) the einsums below.
     """
     Bsz, S, H, P = xh.shape
     N = Bm.shape[-1]
+    hb = None if ops.interpret_mode() else \
+        ssd_kernel.head_block(H, P, N, chunk)
+    if hb is not None:
+        y, final = ssd_kernel.ssd(xh.reshape(Bsz, S, H * P), dt, A, Bm, Cm,
+                                  D, chunk, init_state, hb=hb,
+                                  interpret=False)
+        return y.reshape(Bsz, S, H, P), final
     pad = (-S) % chunk
     if pad:
         xh = jnp.pad(xh, ((0, 0), (0, pad), (0, 0), (0, 0)))

@@ -1,15 +1,19 @@
-"""Compile-only checks of the fused wire kernels for a TPU v5e.
+"""Compile-only checks of the fused wire kernels and the SSD kernel pair
+for a TPU v5e.
 
 Mosaic compiles each kernel for a described (not attached) v5e chip at a
 real mamba2-1.3b bucket: 48 units of 524288 gradients (the per-layer
 w_bc leaves), i.e. 49152 tile rows of 512. Interpret mode cannot see
 what these catch: layouts Mosaic refuses, tiles that overflow VMEM, and
-lowerings with no TPU rule. Nothing runs, so nothing here is a timing.
+lowerings with no TPU rule. The SSD kernels compile at mamba2-1.3b's
+full shape: batch 8 x 2048 tokens, 64 heads of 64, state 128, chunk 64.
+Nothing runs, so nothing here is a timing.
 """
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.kernels import ssd
 from repro.kernels.pack import fields_pack_pallas, fields_unpack_pallas
 from repro.kernels.qsgd import qsgd_pack_pallas_rows, qsgd_unpack_pallas_rows
 from repro.kernels.sign import (majority_pallas, sign_pack_pallas_rows,
@@ -93,3 +97,52 @@ def test_fused_wire_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _kernels(one_chip)[name]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+SSD_B, SSD_S, SSD_H, SSD_P, SSD_N, SSD_Q = 8, 2048, 64, 64, 128, 64
+
+
+def _ssd_kernels(s):
+    """name -> (SSD kernel call or its gradient, argument shapes)."""
+    hb = ssd.head_block(SSD_H, SSD_P, SSD_N, SSD_Q)
+    nc, nhb = SSD_S // SSD_Q, SSD_H // hb
+    x = _sds((SSD_B, SSD_S, SSD_H * SSD_P), jnp.bfloat16, s)
+    bc = _sds((SSD_B, SSD_S, SSD_N), jnp.bfloat16, s)
+    dt_r = _sds((SSD_B, nc, SSD_H, SSD_Q), jnp.float32, s)
+    a = _sds((nhb, hb, 1), jnp.float32, s)
+    dl = _sds((1, SSD_H * SSD_P), jnp.float32, s)
+    state = _sds((SSD_B, nhb, SSD_N, hb * SSD_P), jnp.float32, s)
+    states = _sds((SSD_B, nc, nhb, SSD_N, hb * SSD_P), jnp.float32, s)
+    dt = _sds((SSD_B, SSD_S, SSD_H), jnp.float32, s)
+    heads = _sds((SSD_H,), jnp.float32, s)
+
+    def loss(x, dt, A, Bm, Cm, D):
+        y, fin = ssd.ssd(x, dt, A, Bm, Cm, D, SSD_Q, hb=hb, interpret=False)
+        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(fin)
+    return {
+        "ssd_fwd": (lambda *a_: ssd._fwd_call(
+            *a_, hb=hb, with_states=False, interpret=False),
+            (x, dt_r, a, bc, bc, dl, state)),
+        "ssd_fwd_states": (lambda *a_: ssd._fwd_call(
+            *a_, hb=hb, with_states=True, interpret=False),
+            (x, dt_r, a, bc, bc, dl, state)),
+        "ssd_bwd": (lambda *a_: ssd._bwd_call(
+            *a_, hb=hb, interpret=False),
+            (x, dt_r, a, bc, bc, dl, states, x, state)),
+        "ssd_grad": (jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5)),
+                     (x, dt, heads, bc, bc, heads)),
+    }
+
+
+@pytest.mark.parametrize("name", ["ssd_fwd", "ssd_fwd_states", "ssd_bwd",
+                                  "ssd_grad"])
+def test_ssd_kernel_compiles_for_v5e(one_chip, name):
+    """Each SSD kernel, and the gradient program that joins the forward
+    with states to the backward, at full mamba2-1.3b shape."""
+    fn, args = _ssd_kernels(one_chip)[name]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, name
+    kernels = {"ssd_grad": ("%ssd_fwd_states", "%ssd_bwd")}.get(
+        name, ("%" + name,))
+    for k in kernels:
+        assert k + "." in text, (name, k)
