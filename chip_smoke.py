@@ -21,8 +21,9 @@ One chip, three phases, all in this one process:
 
 --chips 4 runs only what exists across chips: whisper-base on a data=4
 mesh, qsgd(16) layerwise --wire, --collective ring against --collective
-allgather from one seed, three steps each. Losses and parameters must be
-bitwise equal, and every parameter must live on all four devices.
+allgather from one seed, three steps each. The parameters must be the
+published set (biases, learned decoder positions); losses and parameters
+must be bitwise equal, and every parameter must live on all four devices.
 
 Any failed check raises; the process then exits non-zero without the
 verdict. The last line of standard output is the JSON verdict.
@@ -171,6 +172,18 @@ def phase_train():
               flush=True)
 
 
+def published_whisper(params) -> bool:
+    """Whisper-base's published parameter set: a learned table of 448
+    decoder positions and no encoder table (its positions are fixed
+    sinusoids); biases on q, v and out of every attention and on both
+    MLP matrices, none on k."""
+    enc, dec = params["encoder_blocks"], params["decoder_blocks"]
+    biases = {"bq", "bv", "bo", "b_in", "b_out"}
+    return ("enc_pos" not in params and params["dec_pos"].shape[0] == 448
+            and biases <= set(enc) and (biases | {"cbq", "cbv", "cbo"})
+            <= set(dec) and not {"bk", "cbk"} & (set(enc) | set(dec)))
+
+
 def phase_four_chips():
     import jax
     import jax.numpy as jnp
@@ -185,6 +198,8 @@ def phase_four_chips():
     for c, job in jobs.items():
         devs = set(job.mesh.devices.flat)
         check(len(devs) == 4, f"{c}: mesh spans {len(devs)} devices")
+        check(published_whisper(job.params),
+              f"{c}: whisper-base's parameters are not the published set")
     # the two programs compile at once (the jitted steps then reuse them);
     # they run one after the other
     lowered = []

@@ -50,6 +50,9 @@ def config_for_shape(arch: str, shape_name: str
     (long_500k on pure full-attention archs without a SWA variant)."""
     shape = INPUT_SHAPES[shape_name]
     cfg = get_config(arch)
+    if cfg.arch_type == "audio" and shape.seq_len > cfg.max_target_positions:
+        return None, (f"skipped: the decoder's learned position table "
+                      f"holds {cfg.max_target_positions} positions")
     if shape.name != "long_500k":
         return cfg, ""
     if cfg.supports_long_context():

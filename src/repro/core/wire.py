@@ -1266,8 +1266,9 @@ def execute_schedule_stream(schedule, codec: WireCodec,
     (tree, buffers) — or (tree, m_tree, buffers) with state.
 
     `recorder` emits the serialized path's compress/pack/decode spans
-    plus one `hop` span per ring hop (name `hop{h} m{i}`, scope
-    `repro/msg{i}/hop{h}`) and a `collective` span for the reduce —
+    plus one `hop` span per ring hop (category `collective`, name
+    `hop{h} m{i}`, scope `repro/msg{i}/hop{h}`, the bytes each worker
+    sends in it as `nbytes`) and a `collective` span for the reduce —
     what obs.calibrate.measure_stream aggregates into measured exposed
     comm. Under a multi-device shard_map every mark stamps once per
     device; finalize_step(dedupe=True) collapses them.
@@ -1446,8 +1447,9 @@ def execute_schedule_stream(schedule, codec: WireCodec,
                             accs[j] = codec.decode_accumulate(
                                 pay, accs[j], src, dims[j])
                 if rec is not None:
-                    rec.mark([cur[-1], accs[-1]], "hop",
-                             label=f"hop{h} m{mi}", **attrs)
+                    rec.mark([cur[-1], accs[-1]], "hop", cat="collective",
+                             label=f"hop{h} m{mi}",
+                             nbytes=layout.total_nbytes, **attrs)
             state_tok["ctok"] = cur[-1]
         ys, m_news = [], []
         with _scope(mi, "collective"):

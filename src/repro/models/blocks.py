@@ -9,9 +9,9 @@ import jax.numpy as jnp
 from repro.models.dist import (DistConfig, all_gather, axis_index, fdot,
                                pmax, psum, region_in, region_out,
                                tp_region_in, tp_region_out, tp_shared)
-from repro.models.layers import (apply_norm, cache_write, chunked_attention,
-                                 expand_kv, head_mask, mlp, quantize_kv,
-                                 rmsnorm, rope, splitkv_decode)
+from repro.models.layers import (add_bias, apply_norm, cache_write,
+                                 chunked_attention, expand_kv, head_mask, mlp,
+                                 quantize_kv, rmsnorm, rope, splitkv_decode)
 from repro.models.flash import flash_attention
 from repro.models.moe import moe_ffn
 
@@ -33,11 +33,12 @@ def gqa_attention(p: Dict, x: Array, cfg, dist: DistConfig, *, causal=True,
     h = apply_norm(p, f"{prefix}attn_norm", x, cfg, dist)
     hq = region_in(h, dist)
     B, S, _ = hq.shape
-    q = (hq @ p[f"{prefix}wq"])
+    q = add_bias(p, f"{prefix}bq", hq @ p[f"{prefix}wq"])
     Hl = q.shape[-1] // dh
     q = q.reshape(B, S, Hl, dh)
     k = (hq @ tp_shared(p[f"{prefix}wk"], dist.tp)).reshape(B, S, -1, dh)
-    v = (hq @ tp_shared(p[f"{prefix}wv"], dist.tp)).reshape(B, S, -1, dh)
+    v = add_bias(p, f"{prefix}bv", hq @ tp_shared(p[f"{prefix}wv"], dist.tp),
+              dist.tp).reshape(B, S, -1, dh)
     pos = pos_offset + jnp.arange(S)
     if use_rope:
         q = rope(q, pos, cfg.rope_theta)
@@ -47,7 +48,8 @@ def gqa_attention(p: Dict, x: Array, cfg, dist: DistConfig, *, causal=True,
     ve = expand_kv(v, Hl, r, cfg.n_heads, cfg.n_kv_heads)
     o = flash_attention(q, ke, ve, jnp.float32(window), causal, pos_offset)
     o = head_mask(o, cfg, dist, axis=2)
-    out = region_out(o.reshape(B, S, -1) @ p[f"{prefix}wo"], dist)
+    out = add_bias(p, f"{prefix}bo",
+                region_out(o.reshape(B, S, -1) @ p[f"{prefix}wo"], dist))
     cache = None
     if collect_cache:
         Ss = collect_cache // tp_size
@@ -83,17 +85,18 @@ def gqa_cross_attention(p: Dict, x: Array, memory: Array, cfg,
     hq = region_in(h, dist)
     B, S, _ = hq.shape
     mq = tp_region_in(memory, dist.tp)
-    q = (hq @ p["cwq"])
+    q = add_bias(p, "cbq", hq @ p["cwq"])
     Hl = q.shape[-1] // dh
     q = q.reshape(B, S, Hl, dh)
     k = (mq @ tp_shared(p["cwk"], dist.tp)).reshape(B, memory.shape[1], -1, dh)
-    v = (mq @ tp_shared(p["cwv"], dist.tp)).reshape(B, memory.shape[1], -1, dh)
+    v = add_bias(p, "cbv", mq @ tp_shared(p["cwv"], dist.tp),
+              dist.tp).reshape(B, memory.shape[1], -1, dh)
     r = axis_index(dist.tp)
     ke = expand_kv(k, Hl, r, cfg.n_heads, cfg.n_kv_heads)
     ve = expand_kv(v, Hl, r, cfg.n_heads, cfg.n_kv_heads)
     o = flash_attention(q, ke, ve, jnp.float32(0), False, 0)
     o = head_mask(o, cfg, dist, axis=2)
-    return region_out(o.reshape(B, S, -1) @ p["cwo"], dist)
+    return add_bias(p, "cbo", region_out(o.reshape(B, S, -1) @ p["cwo"], dist))
 
 
 def gqa_attention_decode(p: Dict, x: Array, cache: Dict, pos: Array, cfg,
@@ -109,13 +112,16 @@ def gqa_attention_decode(p: Dict, x: Array, cache: Dict, pos: Array, cfg,
     dh = cfg.d_head
     h = apply_norm(p, f"{prefix}attn_norm", x, cfg)
     hq = tp_region_in(h, dist.tp)
-    q = fdot(hq, p[f"{prefix}wq"], fd.get(f"{prefix}wq"), dist)
+    q = add_bias(p, f"{prefix}bq",
+              fdot(hq, p[f"{prefix}wq"], fd.get(f"{prefix}wq"), dist))
     Hl = q.shape[-1] // dh
     q = q.reshape(B, 1, Hl, dh)
     k = fdot(hq, tp_shared(p[f"{prefix}wk"], dist.tp),
              fd.get(f"{prefix}wk"), dist).reshape(B, 1, -1, dh)
-    v = fdot(hq, tp_shared(p[f"{prefix}wv"], dist.tp),
-             fd.get(f"{prefix}wv"), dist).reshape(B, 1, -1, dh)
+    v = add_bias(p, f"{prefix}bv",
+              fdot(hq, tp_shared(p[f"{prefix}wv"], dist.tp),
+                   fd.get(f"{prefix}wv"), dist),
+              dist.tp).reshape(B, 1, -1, dh)
     if use_rope:
         pvec = pos[None] if pos.ndim == 0 else pos
         q = rope(q, pvec[None, :], cfg.rope_theta)
@@ -154,9 +160,9 @@ def gqa_attention_decode(p: Dict, x: Array, cache: Dict, pos: Array, cfg,
                            window=window)
         new_cache.update(k=ck, v=cv, slot_pos=spos)
     o = head_mask(o, cfg, dist, axis=1)
-    out = tp_region_out(
+    out = add_bias(p, f"{prefix}bo", tp_region_out(
         fdot(o.reshape(B, 1, -1).astype(x.dtype), p[f"{prefix}wo"],
-             fd.get(f"{prefix}wo"), dist), dist.tp)
+             fd.get(f"{prefix}wo"), dist), dist.tp))
     return out, new_cache
 
 
@@ -280,7 +286,9 @@ def decoder_block(p: Dict, x: Array, cfg, dist: DistConfig, *, window=0,
                   pos_offset=0, causal=True, use_rope=True,
                   memory: Optional[Array] = None, collect_cache: int = 0,
                   tp_size: int = 1):
-    """Generic transformer block. Returns (x, aux_loss, cache|None)."""
+    """Generic transformer block. Returns (x, aux_loss, cache|None).
+    The cross-attention's operations are named `repro/cross_attention`
+    in XLA's profile."""
     aux = jnp.zeros((), jnp.float32)
     cache = None
     if cfg.attention == "mla":
@@ -294,7 +302,8 @@ def decoder_block(p: Dict, x: Array, cfg, dist: DistConfig, *, window=0,
                                  collect_cache=collect_cache, tp_size=tp_size)
         x = x + a
     if memory is not None:
-        x = x + gqa_cross_attention(p, x, memory, cfg, dist)
+        with jax.named_scope("repro/cross_attention"):
+            x = x + gqa_cross_attention(p, x, memory, cfg, dist)
     h = apply_norm(p, "mlp_norm", x, cfg, dist)
     if cfg.n_experts:
         B, S, d = h.shape

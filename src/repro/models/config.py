@@ -49,6 +49,12 @@ class ModelConfig:
     encoder_layers: int = 0
     frontend: str = "none"      # none | audio_stub | vision_stub
     frontend_seq: int = 0       # audio frames / vision patches per sample
+    max_target_positions: int = 0  # audio: rows of the learned decoder
+                                   # position table (whisper: 448); a
+                                   # sequence or decode past it is an error
+    bias: bool = False          # whisper's linear biases: q, v and out of
+                                # every attention, both MLP matrices (none
+                                # on k)
     # misc
     use_rope: bool = True       # False: sinusoidal absolute positions
     norm: str = "rmsnorm"       # rmsnorm | layernorm
@@ -140,7 +146,11 @@ class ModelConfig:
         elif self.arch_type == "audio":
             n += self.encoder_layers * (attn_params() + mlp_params() + 4 * d)
             n += L * (2 * attn_params() + mlp_params() + 6 * d)  # self+cross
-            n += self.frontend_seq * d  # learned positions (encoder)
+            n += self.max_target_positions * d  # learned decoder positions
+            if self.bias:   # q, v, out of each attention; MLP in and out
+                attn_b, mlp_b = self.d_q + self.d_kv + d, self.d_ff + d
+                n += self.encoder_layers * (attn_b + mlp_b)
+                n += L * (2 * attn_b + mlp_b)
         else:
             n += L * (attn_params() + mlp_params() + 4 * d)
         return n

@@ -76,6 +76,16 @@ def rope(x: Array, pos: Array, theta: float) -> Array:
 # MLP (column->row parallel)
 # --------------------------------------------------------------------------
 
+def add_bias(p: dict, name: str, y: Array, tp: Optional[str] = None) -> Array:
+    """y + p[name] where the configuration has that bias (whisper's), else
+    y. `tp` marks a bias used whole on every tensor-parallel rank whose
+    gradient each rank holds in part (v's, like wv's)."""
+    if name not in p:
+        return y
+    b = p[name] if tp is None else tp_shared(p[name], tp)
+    return y + b.astype(y.dtype)
+
+
 def mlp(p: dict, x: Array, cfg, dist: DistConfig, fd=None) -> Array:
     """fd: per-leaf fsdp dims for 2D-TP decode (see dist.fdot); None on the
     train path where weights arrive FSDP-gathered."""
@@ -85,9 +95,12 @@ def mlp(p: dict, x: Array, cfg, dist: DistConfig, fd=None) -> Array:
     if cfg.mlp == "swiglu":
         h = jax.nn.silu(fdot(xi, p["w_gate"], fd.get("w_gate"), dist)) * \
             fdot(xi, p["w_in"], fd.get("w_in"), dist)
-    else:
-        h = jax.nn.gelu(fdot(xi, p["w_in"], fd.get("w_in"), dist))
-    return region_out(fdot(h, p["w_out"], fd.get("w_out"), dist), dist)
+    else:   # whisper: exact (erf) GELU
+        h = jax.nn.gelu(add_bias(p, "b_in", fdot(xi, p["w_in"],
+                                                 fd.get("w_in"), dist)),
+                        approximate=False)
+    return add_bias(p, "b_out", region_out(
+        fdot(h, p["w_out"], fd.get("w_out"), dist), dist))
 
 
 # --------------------------------------------------------------------------

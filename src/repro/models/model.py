@@ -64,6 +64,25 @@ def _add_attn(pb: ParamBuilder, base: str, cfg: ModelConfig, tp_size: int,
            fan_in_dim=len(lead))
     pb.add(f"{base}/{prefix}wo", lead + (Hp * dh, d), la + ("tp", F),
            stacked=stk, fan_in_dim=len(lead))
+    if cfg.bias:
+        _add_attn_bias(pb, f"{base}/{prefix}", cfg, tp_size, L)
+
+
+def _add_attn_bias(pb: ParamBuilder, base: str, cfg: ModelConfig,
+                   tp_size: int, L: Optional[int], names=("bq", "bv", "bo")):
+    """Whisper's attention biases: on q (sharded like wq's heads), on v
+    (replicated like wv, whose grads sync over tp) and on the output;
+    the key projection has none."""
+    lead = (L,) if L is not None else ()
+    la = (None,) if L is not None else ()
+    stk = L is not None
+    Hp = _ceil_to(cfg.n_heads, tp_size)
+    pb.add(base + names[0], lead + (Hp * cfg.d_head,), la + ("tp",),
+           stacked=stk, init="zeros")
+    pb.add(base + names[1], lead + (cfg.d_kv,), la + (None,), stacked=stk,
+           tp_grad_sync=True, init="zeros")
+    pb.add(base + names[2], lead + (cfg.d_model,), la + (None,),
+           stacked=stk, init="zeros")
 
 
 def _add_mla(pb: ParamBuilder, base: str, cfg: ModelConfig, tp_size: int,
@@ -107,6 +126,11 @@ def _add_mlp(pb: ParamBuilder, base: str, cfg: ModelConfig, L: Optional[int],
            stacked=stk, fan_in_dim=len(lead))
     pb.add(f"{base}/{prefix}{names[2]}", lead + (ff, d), la + ("tp", F),
            stacked=stk, fan_in_dim=len(lead))
+    if cfg.bias:
+        pb.add(f"{base}/{prefix}b_in", lead + (ff,), la + ("tp",),
+               stacked=stk, init="zeros")
+        pb.add(f"{base}/{prefix}b_out", lead + (d,), la + (None,),
+               stacked=stk, init="zeros")
 
 
 def _add_moe(pb: ParamBuilder, base: str, cfg: ModelConfig, L: int, F,
@@ -200,8 +224,8 @@ def declare_params(cfg: ModelConfig, tp_size: int) -> ParamBuilder:
         _add_mlp(pb, "shared", cfg, None, F)
     elif cfg.arch_type == "audio":
         Le = cfg.encoder_layers
-        pb.add("enc_pos", (cfg.frontend_seq, d), (None, None), scale=0.02,
-               fan_in_dim=1)
+        pb.add("dec_pos", (cfg.max_target_positions, d), (None, None),
+               scale=0.02, fan_in_dim=1)
         _add_attn(pb, "encoder_blocks", cfg, tp_size, Le, F)
         _add_mlp(pb, "encoder_blocks", cfg, Le, F)
         _add_norm(pb, "enc_final_norm", (d,), cfg, False)
@@ -217,6 +241,9 @@ def declare_params(cfg: ModelConfig, tp_size: int) -> ParamBuilder:
         pb.add("decoder_blocks/cwo",
                (L, _ceil_to(cfg.n_heads, tp_size) * cfg.d_head, d),
                (None, "tp", F), stacked=True, fan_in_dim=1)
+        if cfg.bias:
+            _add_attn_bias(pb, "decoder_blocks/", cfg, tp_size, L,
+                           names=("cbq", "cbv", "cbo"))
         _add_mlp(pb, "decoder_blocks", cfg, L, F)
     else:
         raise ValueError(cfg.arch_type)
@@ -522,12 +549,10 @@ class Model:
         return l + 0.01 * aux
 
     def _loss_audio(self, params, batch, key, comp, remat):
-        cfg = self.cfg
         kb = key_to_bits(key)
         mem = self._encode_audio(params, batch["frames"], comp, key, remat)
         x = self._embed(params, batch["tokens"], kb, comp)
-        x = x + sinusoid_positions(jnp.arange(x.shape[1]),
-                                   cfg.d_model).astype(x.dtype)[None]
+        x = x + self._decoder_positions(params, x.shape[1], x.dtype)
         x, aux, _ = self._run_stack(params["decoder_blocks"],
                                     self.meta["decoder_blocks"], x, comp,
                                     key, block_kind="decoder", memory=mem,
@@ -536,14 +561,35 @@ class Model:
                              self.dist_nosp)
 
     def _encode_audio(self, params, frames, comp, key, remat):
+        """Whisper's encoder over the frame embeddings: fixed sinusoidal
+        positions, the stack, the final LayerNorm. Its operations are
+        named `repro/encoder` in XLA's profile."""
         cfg = self.cfg
-        x = frames.astype(jnp.dtype(cfg.dtype)) + params["enc_pos"][None]
-        x, _, _ = self._run_stack(params["encoder_blocks"],
-                                  self.meta["encoder_blocks"], x, comp,
-                                  jax.random.fold_in(key, 99),
-                                  block_kind="decoder", causal=False,
-                                  remat=remat)
-        return apply_norm(params, "enc_final_norm", x, cfg)
+        with jax.named_scope("repro/encoder"):
+            x = frames.astype(jnp.dtype(cfg.dtype))
+            x = x + sinusoid_positions(jnp.arange(x.shape[1]),
+                                       cfg.d_model).astype(x.dtype)[None]
+            x, _, _ = self._run_stack(params["encoder_blocks"],
+                                      self.meta["encoder_blocks"], x, comp,
+                                      jax.random.fold_in(key, 99),
+                                      block_kind="decoder", causal=False,
+                                      remat=remat)
+            return apply_norm(params, "enc_final_norm", x, cfg)
+
+    def _check_positions(self, n: int) -> None:
+        """The audio decoder's learned position table holds
+        `max_target_positions` positions and no more."""
+        cap = self.cfg.max_target_positions
+        if self.cfg.arch_type == "audio" and n > cap:
+            raise ValueError(
+                f"{self.cfg.name}: the decoder's learned position table "
+                f"holds {cap} positions; {n} were asked for")
+
+    def _decoder_positions(self, params, S: int, dtype):
+        """(1, S, d) embeddings of the audio decoder's positions 0..S-1:
+        the first S rows of the learned table."""
+        self._check_positions(S)
+        return params["dec_pos"][None, :S].astype(dtype)
 
     # ---- prefill ---------------------------------------------------------
     def prefill(self, params, batch, key, remat: bool = True,
@@ -562,8 +608,7 @@ class Model:
             mem = self._encode_audio(params, batch["frames"], comp, key,
                                      remat)
             x = self._embed(params, batch["tokens"], kb, comp)
-            x = x + sinusoid_positions(jnp.arange(S),
-                                       cfg.d_model).astype(x.dtype)[None]
+            x = x + self._decoder_positions(params, S, x.dtype)
             x, _, caches = self._run_stack(
                 params["decoder_blocks"], self.meta["decoder_blocks"], x,
                 comp, key, block_kind="decoder", memory=mem,
@@ -608,7 +653,11 @@ class Model:
         key = jax.random.key(0)
         comp = None
         x = self._embed_decode(params, token[:, None])
-        if not cfg.use_rope:
+        if cfg.arch_type == "audio":
+            self._check_positions(self._decode_capacity(cache, pos))
+            x = x + jnp.take(params["dec_pos"], pos[None],
+                             axis=0).astype(x.dtype)[None]
+        elif not cfg.use_rope:
             x = x + sinusoid_positions(pos[None], cfg.d_model
                                        ).astype(x.dtype)[None]
 
@@ -757,9 +806,19 @@ class Model:
             x, new_tail = jax.lax.scan(tbody, x, (p_tail, tail_cache, kbt))
         return x, {"mamba": new_mc, "attn": new_ac, "tail": new_tail}
 
+    def _decode_capacity(self, cache, pos) -> int:
+        """The positions a decode step may reach: the position itself
+        where it is known while tracing, else the self-attention cache's
+        length (slots over all tensor-parallel ranks)."""
+        if not isinstance(pos, jax.core.Tracer):
+            return int(pos) + 1
+        slots = cache["self"]["slot_pos"].shape[-1]
+        return slots * self.tp_size
+
     # ---- cache layouts ----------------------------------------------------
     def cache_len(self, seq_len: int) -> int:
         cfg = self.cfg
+        self._check_positions(seq_len)
         if cfg.sliding_window > 0 and cfg.swa_pattern == 0:
             return min(seq_len, cfg.sliding_window)
         return seq_len

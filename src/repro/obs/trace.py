@@ -108,12 +108,14 @@ class TraceRecorder:
              dims: Optional[Sequence[int]] = None,
              n_units: Optional[int] = None,
              codec: Optional[str] = None,
-             label: Optional[str] = None) -> None:
+             label: Optional[str] = None,
+             nbytes: Optional[int] = None) -> None:
         """Register one pipeline-stage end: static attribution now, a
-        host-clock stamp (data-dependent on `dep`) per executed step."""
+        host-clock stamp (data-dependent on `dep`) per executed step.
+        `nbytes`: the bytes the stage sends (a ring hop's)."""
         self._mark(dep, stage, cat=cat, message=message,
                    bucket_ids=bucket_ids, dims=dims, n_units=n_units,
-                   codec=codec, label=label)
+                   codec=codec, label=label, nbytes=nbytes)
 
     def _mark(self, dep, stage: str, **meta) -> None:
         mid = len(self._meta)
@@ -206,7 +208,8 @@ class TraceRecorder:
                 else meta["stage"])
             args = {"step": step, "stage": meta["stage"],
                     "schema_version": TRACE_SCHEMA_VERSION}
-            for k in ("message", "bucket_ids", "dims", "n_units", "codec"):
+            for k in ("message", "bucket_ids", "dims", "n_units", "codec",
+                      "nbytes"):
                 if k in meta:
                     args[k] = (list(meta[k])
                                if isinstance(meta[k], tuple) else meta[k])

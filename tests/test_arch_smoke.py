@@ -101,6 +101,30 @@ def test_smoke_wire_train_step(arch):
         assert not bool(jnp.isnan(leaf).any())
 
 
+def test_whisper_decoder_positions_end_at_448():
+    """Whisper learns 448 decoder positions: a decode at position 448, a
+    cache or a training sequence longer than the table is refused."""
+    cfg = get_smoke("whisper-base")
+    assert cfg.max_target_positions == 448
+    m = Model(cfg, DistConfig())
+    assert m.param_shapes()["dec_pos"].shape == (448, cfg.d_model)
+    assert "enc_pos" not in m.param_shapes()
+    params = jax.jit(m.init)(KEY)
+    batch = _batch(cfg)
+    _, cache = m.prefill(params, batch, jax.random.key(2), cache_len=448)
+    tok = jnp.zeros((B,), jnp.int32)
+    lg, _ = m.decode_step(params, tok, jnp.int32(447), cache)
+    assert not bool(jnp.isnan(lg).any())
+    with pytest.raises(ValueError, match="holds 448 positions"):
+        m.decode_step(params, tok, jnp.int32(448), cache)
+    with pytest.raises(ValueError, match="holds 448 positions"):
+        m.prefill(params, batch, jax.random.key(2), cache_len=449)
+    long = {**batch, "tokens": jnp.ones((B, 449), jnp.int32),
+            "targets": jnp.ones((B, 449), jnp.int32)}
+    with pytest.raises(ValueError, match="holds 448 positions"):
+        m.loss(params, long, jax.random.key(1))
+
+
 def test_decode_matches_prefill_continuation():
     """Teacher-forced decode after prefill reproduces the prefill logits
     of the next position (cache consistency, dense arch)."""

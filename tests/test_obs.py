@@ -269,6 +269,32 @@ def test_zero_overhead_wire_path():
     assert_trees_bitwise(refb, gotb, "wire-buffers")
 
 
+def test_audio_model_scopes_name_ops_only():
+    """The audio model's encoder stack and cross-attention are named
+    scopes: the names reach the lowered program's debug info alone (the
+    program text without it is free of them) and stage no callback."""
+    from repro.configs.registry import get_smoke
+    from repro.data import frames_stub
+    from repro.models import DistConfig, Model
+    cfg = get_smoke("whisper-base")
+    m = Model(cfg, DistConfig())
+    params = m.init(KEY)
+    batch = {"tokens": jnp.ones((2, 8), jnp.int32),
+             "targets": jnp.ones((2, 8), jnp.int32),
+             "frames": frames_stub(KEY, 2, cfg.frontend_seq, cfg.d_model)}
+
+    def loss(p):
+        return m.loss(p, batch, KEY)
+
+    lowered = jax.jit(loss).lower(params)
+    text = lowered.as_text(debug_info=True)
+    assert "repro/encoder" in text and "repro/cross_attention" in text
+    bare = lowered.as_text()
+    assert "repro/encoder" not in bare
+    assert "repro/cross_attention" not in bare
+    assert count_debug_callbacks(loss, params) == 0
+
+
 # ---------------------------------------------------------------------------
 # retrace watchdog
 # ---------------------------------------------------------------------------
